@@ -124,8 +124,7 @@ def bench_conv_batch_fold():
 
 def bench_kernel_walltime():
     """Kernel sanity timings at ``WALLTIME_TARGET`` (interpret by
-    default — not TPU performance; ``run.py --target compiled`` times
-    the same calls through the compiled CPU lowering)."""
+    default, or lax) — host wall clocks, not TPU performance."""
     from repro.core.exec_target import resolve_target
     from repro.kernels.attention_block.ops import flash_attention
     from repro.kernels.conv_lb.ops import conv2d_lb
@@ -159,53 +158,5 @@ def bench_kernel_walltime():
     return rows
 
 
-def bench_conv_compiled():
-    """Compiled execution gate: wall clock of the *same* conv under
-    ``interpret=False`` (the registered CPU lowering — straight-line
-    XLA over the kernel's grid schedule) vs the Pallas interpreter on
-    one mosaic-legal geometry, plus fwd+grad numerics vs lax.  The
-    first real (synced, non-null ``us_per_call``) compiled rows of the
-    repro."""
-    from repro.core.exec_target import COMPILED, INTERPRET, LAX
-    from repro.kernels.conv_lb.ops import conv2d_lb
-
-    # 256 input channels split the reduction (nci=2): per-step
-    # interpreter overhead doubles while the compiled straight-line
-    # schedule stays flat — a robust, not knife-edge, speedup gate
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 8, 256))
-    w = jax.random.normal(jax.random.PRNGKey(1),
-                          (3, 3, 256, 128)) * 0.05
-
-    def call(tgt):
-        return conv2d_lb(x, w, padding=1, target=tgt)
-
-    # warm both jit caches first: the compiled path's first call pays
-    # the unrolled-grid XLA compile, which is not the steady state
-    call(COMPILED).block_until_ready()
-    call(INTERPRET).block_until_ready()
-    us_c = _time_call(call, COMPILED)
-    us_i = _time_call(call, INTERPRET)
-
-    def grads(tgt):
-        return jax.grad(
-            lambda a, b: (conv2d_lb(a, b, padding=1, relu=True,
-                                    target=tgt) ** 2).mean(),
-            argnums=(0, 1))(x, w)
-
-    yc, yl = call(COMPILED), call(LAX)
-    maxerr = float(jnp.max(jnp.abs(yc - yl)))
-    for gc, gl in zip(grads(COMPILED), grads(LAX)):
-        maxerr = max(maxerr, float(jnp.max(jnp.abs(gc - gl))))
-    return [
-        ("kernels/conv_lb_8x256_compiled_us", us_c, 0),
-        ("kernels/conv_lb_8x256_interp_us", us_i, 0),
-        ("kernels/conv_lb_8x256/compiled_speedup_x", None,
-         round(us_i / us_c, 2)),
-        ("kernels/conv_lb_8x256/compiled_numeric_maxerr", None,
-         float(f"{maxerr:.2e}")),
-    ]
-
-
 ALL_KERNELS = [bench_matmul_traffic, bench_conv_traffic,
-               bench_conv_batch_fold, bench_kernel_walltime,
-               bench_conv_compiled]
+               bench_conv_batch_fold, bench_kernel_walltime]

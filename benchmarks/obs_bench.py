@@ -190,13 +190,6 @@ def bench_obs_kernel_gbps():
     conv_row("conv_lb_48",
              jax.random.normal(jax.random.PRNGKey(0), (1, 48, 48, 8)),
              jax.random.normal(jax.random.PRNGKey(1), (3, 3, 8, 16)))
-    # compiled (interpret=False) achieved-GB/s on the mosaic-legal
-    # geometry: the bytes-vs-seconds pipeline over a *compiled* kernel
-    conv_row("conv_lb_8x128_compiled",
-             jax.random.normal(jax.random.PRNGKey(0), (2, 8, 8, 128)),
-             jax.random.normal(jax.random.PRNGKey(1),
-                               (3, 3, 128, 128)) * 0.05,
-             target="compiled")
 
     x = jax.random.normal(jax.random.PRNGKey(0), (256, 256))
     w = jax.random.normal(jax.random.PRNGKey(1), (256, 256))

@@ -20,12 +20,12 @@ def bench_plan_audit():
     """Audit every vgg/resnet node (fwd+dgrad+wgrad) at 1 MiB: the
     interpret-profile (structural) legality fraction and the symbolic
     traffic/bound cross-audit, plus the mosaic-profile legality
-    fraction at the kernels' execution budget — the compiled-mode
-    readiness number, not a gate yet."""
+    fraction of the plans a ``target="compiled"`` run executes (planned
+    at the mosaic profile and the scoped VMEM limit)."""
     import jax
 
     from repro.analysis.plan_check import TARGET_MOSAIC, audit_graph
-    from repro.core.tpu_adapter import VMEM_BYTES
+    from repro.core.tpu_adapter import VMEM_LIMIT_BYTES
     from repro.models.cnn import init_vgg, resnet_graph, vgg_graph
 
     graphs = [(vgg_graph(init_vgg(jax.random.PRNGKey(0))), 224),
@@ -46,12 +46,12 @@ def bench_plan_audit():
                  mismatches))
     rows.append(("audit/vgg+resnet/plans_checked", None, n_plans))
 
-    # mosaic profile at the execution budget: how much of the stack is
-    # already compiled-mode legal (informational row, ungated)
+    # the compiled path's own plans (fwd+dgrad+wgrad) under the
+    # mosaic profile at the execution budget
     m_legal = m_plans = 0
     for graph, hw in graphs:
         a = audit_graph(graph, hw, hw, batch=8,
-                        vmem_budget=VMEM_BYTES // 2, training=False,
+                        vmem_budget=VMEM_LIMIT_BYTES, training=True,
                         target=TARGET_MOSAIC)
         m_legal += a.n_legal
         m_plans += a.n_plans

@@ -16,8 +16,7 @@ def main() -> None:
                     help="also write rows as JSON to PATH "
                          "(e.g. BENCH_2.json)")
     ap.add_argument("--target", default=None,
-                    choices=("interpret", "compiled", "lax",
-                             "account-only"),
+                    choices=("interpret", "lax"),
                     help="execution target for the kernel walltime "
                          "benches (default: interpret)")
     args = ap.parse_args()

@@ -142,47 +142,5 @@ def bench_serve_loop_bursty():
     ]
 
 
-def bench_serve_compiled_smoke():
-    """Real-compute serving through the *compiled* target: a small
-    lane-aligned conv stack (3->128->128 @ 8x8) so every layer has a
-    mosaic-legal plan, served end to end with ``interpret=False``.
-    The CPU-lowering call counter proves the dispatches ran compiled
-    kernels (not the interpreter, not silent lax fallbacks)."""
-    import time
-
-    import jax
-
-    from repro.kernels import pallas_cpu
-    from repro.models.graph import ConvGraph, ConvNode, init_graph
-    from repro.serve import ImageServer
-
-    graph = ConvGraph(name="compiled-smoke", nodes=(
-        ConvNode(name="stem", ci=3, co=128),
-        ConvNode(name="body", ci=128, co=128),
-    ))
-    params = init_graph(jax.random.PRNGKey(0), graph, n_classes=10)
-    server = ImageServer(params, 8, 8, graph=graph, buckets=(1, 2),
-                         wait_budget=0.01, target="compiled")
-    key = jax.random.PRNGKey(1)
-    before = pallas_cpu.COMPILED_CALLS
-    # warm: the first dispatch pays plan + unrolled-XLA compile
-    server.submit(jax.random.normal(key, (2, 8, 8, 3)))
-    server.poll()
-    t0 = time.perf_counter()
-    for rid in range(4):
-        k = jax.random.fold_in(key, rid)
-        server.submit(jax.random.normal(k, (1 + rid % 2, 8, 8, 3)))
-        server.poll()
-    server.drain()
-    wall_us = (time.perf_counter() - t0) * 1e6
-    s = server.ledger.summary()
-    return [
-        ("serve/compiled_smoke/dispatch_us", wall_us / 4,
-         s["dispatches"]),
-        ("serve/compiled_smoke/compiled_calls", None,
-         pallas_cpu.COMPILED_CALLS - before),
-    ]
-
-
 ALL_SERVE = [bench_serve_traffic, bench_resnet_serve_traffic,
-             bench_serve_loop_bursty, bench_serve_compiled_smoke]
+             bench_serve_loop_bursty]

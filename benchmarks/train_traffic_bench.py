@@ -73,63 +73,6 @@ def bench_resnet_train_traffic():
     ]
 
 
-def bench_train_backward_compiled():
-    """Compiled end-to-end *training step*: ``jax.grad`` through the
-    Pallas forward, the lhs-dilated strided dgrad and the
-    dW-stationary wgrad kernel, timed under ``interpret=False`` (the
-    registered straight-line CPU lowering) vs the Pallas interpreter,
-    with the full gradient checked against the lax VJP.  The gate that
-    the backward pass now *executes* through the paper dataflow at
-    every target — and that compiling it wins wall clock, not just
-    accounting."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.exec_target import COMPILED, INTERPRET, LAX
-    from repro.kernels.conv_lb.ops import (conv2d_lb,
-                                           exec_fallback_counts,
-                                           reset_fallback_counts)
-    from repro.obs import timed_call
-
-    # 512 input channels split the reduction across several ci-blocks:
-    # the interpreter pays its per-grid-step dispatch on every one
-    # while the compiled straight-line schedule stays flat — the same
-    # robust (not knife-edge) gate recipe as ``bench_conv_compiled``
-    kx, k1, k2 = jax.random.split(jax.random.PRNGKey(0), 3)
-    x = jax.random.normal(kx, (2, 8, 8, 512))
-    w1 = jax.random.normal(k1, (3, 3, 512, 256)) * 0.1  # stride-2 layer
-    w2 = jax.random.normal(k2, (3, 3, 256, 256)) * 0.1
-
-    def loss(params, tgt):
-        w1, w2 = params
-        y = conv2d_lb(x, w1, stride=2, padding=1, relu=True, target=tgt)
-        y = conv2d_lb(y, w2, padding=1, target=tgt)
-        return (y ** 2).mean()
-
-    def step(tgt):
-        return jax.block_until_ready(
-            jax.grad(loss)((w1, w2), tgt))
-
-    reset_fallback_counts()
-    step(COMPILED)                       # warm both jit caches first:
-    step(INTERPRET)                      # compile time is not steady
-    fallbacks = sum(exec_fallback_counts().values())
-    us_c = timed_call(lambda: step(COMPILED), name="bench.train")
-    us_i = timed_call(lambda: step(INTERPRET), name="bench.train")
-    gc, gl = step(COMPILED), step(LAX)
-    maxerr = max(float(jnp.max(jnp.abs(a - b)))
-                 for a, b in zip(gc, gl))
-    return [
-        ("train/bwd_2layer_s2/train_compiled_us", us_c, 0),
-        ("train/bwd_2layer_s2/train_interp_us", us_i, 0),
-        ("train/bwd_2layer_s2/train_compiled_speedup_x", None,
-         round(us_i / us_c, 2)),
-        ("train/bwd_2layer_s2/grad_numeric_maxerr", None,
-         float(f"{maxerr:.2e}")),
-        ("train/bwd_2layer_s2/exec_fallbacks", None, fallbacks),
-    ]
-
-
 def bench_wgrad_traffic_executed():
     """The dW-stationary kernel's *measured* traffic vs its Eq. (15)
     bound: execute ``wgrad_lb_call`` on early/mid/late VGG16
@@ -188,4 +131,4 @@ def bench_wgrad_traffic_executed():
 
 
 ALL_TRAIN = [bench_train_traffic, bench_resnet_train_traffic,
-             bench_train_backward_compiled, bench_wgrad_traffic_executed]
+             bench_wgrad_traffic_executed]

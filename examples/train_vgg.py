@@ -20,6 +20,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.core.compile_cache import enable_compile_cache
 from repro.models.cnn import (init_vgg, vgg_loss,
                               vgg_training_step_report)
 
@@ -44,8 +45,8 @@ def main():
     ap.add_argument("--target", default="interpret",
                     choices=("interpret", "compiled", "lax"),
                     help="execution backend for the training step "
-                         "(compiled runs the Pallas kernels with "
-                         "interpret=False)")
+                         "(compiled runs the Mosaic kernels; needs a "
+                         "TPU)")
     ap.add_argument("--paper-scale", action="store_true",
                     help="also report the account-only VGG16/224x224 "
                          "training-step economics")
@@ -54,6 +55,7 @@ def main():
                          "event log at PATH.jsonl): planning spans, "
                          "per-step spans, the training report span")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = None
     if args.trace:

@@ -6,10 +6,8 @@ real breakage, and each is mechanically checkable from the source
 alone:
 
 ``L001`` ``jax.shard_map`` / ``check_vma`` must be imported only
-through :mod:`repro.parallel.compat`: the compat shim owns the
-0.4.x/0.5.x API drift (``jax.experimental.shard_map`` vs
-``jax.shard_map``, ``check_rep`` vs ``check_vma``); a direct import
-works on exactly one pinned version.
+through :mod:`repro.parallel.compat`, the one place they are spelled,
+so a move of that API between JAX releases is one edit.
 
 ``L002`` ``hypothesis`` must be imported only through
 ``tests/_hypothesis_compat``: the container has no hypothesis wheel,

@@ -23,9 +23,10 @@ records that the autotuner's favourite ASIC-budget plans (tiny
     operands and the residual/bias epilogue panels, grid
     divisibility, halo-extended input windows in bounds, psum tile
     shape, pool alignment — always ``error``) and Mosaic alignment
-    rules (``SUBLANE``/``LANE`` tiles per dtype, unblocked halo
-    offsets, MXU reduction fill — ``error`` under the ``mosaic``
-    target, ``warn`` under ``interpret``), returning structured
+    rules (``SUBLANE``/``LANE`` tiles per dtype, the full-row x tile,
+    the compiled working set and tile size, MXU reduction fill —
+    ``error`` under the ``mosaic`` target, ``warn`` under
+    ``interpret``), returning structured
     :class:`Diagnostic` records with rule ids and repair hints.
     Conv and matmul share one rule implementation
     (:func:`_lane_rule` / :func:`_sublane_rule`), so every kernel
@@ -59,7 +60,8 @@ import math
 
 from repro.core.dataflow import Traffic
 from repro.core.layer import ceil_div
-from repro.core.tpu_adapter import (LANE, MXU_DIM, VMEM_BYTES,
+from repro.core.tpu_adapter import (LANE, MOSAIC_TILE_BYTES, MXU_DIM,
+                                    VMEM_LIMIT_BYTES, round_up,
                                     sublane_for)
 
 TARGET_INTERPRET = "interpret"
@@ -98,8 +100,17 @@ RULES = {
     "mosaic.sublane": "a block's second-minor dim must be a sublane "
                       "multiple for the dtype (f32 8 / bf16 16 / "
                       "int8 32) or cover the full dim",
-    "mosaic.offset": "unblocked halo offsets (tile * stride strides) "
-                     "must land on sublane-aligned rows",
+    "mosaic.xtile": "the x tile is one sublane-aligned span of the "
+                    "whole padded output row (the kernel merges "
+                    "(b, y, x) rows, and fetches full-width halos)",
+    "mosaic.vmem": "the compiled kernel's (sublane, LANE)-tiled "
+                   "buffers and temporaries must fit the scoped VMEM "
+                   "limit",
+    "mosaic.tile": "no in-kernel value may exceed MOSAIC_TILE_BYTES "
+                   "(Mosaic unrolls vector code over it)",
+    "mosaic.stride": "a strided window read or lhs-dilation store is "
+                     "a Mosaic strided access: its input block's last "
+                     "dim must fit one LANE tile",
     "mosaic.mxu": "a reduction slice far below the 128-wide MXU "
                   "leaves the systolic array underfilled (perf, not "
                   "legality)",
@@ -210,7 +221,7 @@ def check_conv_plan(plan, *, batch: int = 1, dtype_bytes: int = 4,
     (re-derived independently here, so planner drift is caught
     *before* any kernel is built) plus the Mosaic tiling rules a
     compiled ``pallas_call`` would enforce."""
-    budget = VMEM_BYTES // 2 if vmem_budget is None else vmem_budget
+    budget = VMEM_LIMIT_BYTES if vmem_budget is None else vmem_budget
     blk = plan.blocks
     sy, sx = plan.stride
     ekh = (plan.hk - 1) * plan.dilation[0] + 1
@@ -317,24 +328,33 @@ def check_conv_plan(plan, *, batch: int = 1, dtype_bytes: int = 4,
                       "weight block", target, where)
     if d:
         diags.append(d)
-    if plan.wo_pad // blk.x > 1:
-        # unblocked halo tiles index by element offset xi*x_block*sx:
-        # every offset must land on a sublane-aligned input row.  An
-        # lhs-dilated plan walks the compact plane, so the advance is
-        # the compact step block*stride / lhs_dilation
-        sub = sublane_for(dtype_bytes)
-        adv = blk.x * sx
-        if getattr(plan, "lhs_dilated", False):
-            ldx = plan.lhs_dilation[1]
-            if ldx > 1 and adv % ldx == 0:
-                adv //= ldx
-        if adv % sub:
-            diags.append(Diagnostic(
-                rule="mosaic.offset", severity=_mosaic_sev(target),
-                where=where,
-                message=f"halo x-offsets advance by {adv} "
-                        f"rows, not a {sub}-row multiple",
-                hint=f"make the x advance a multiple of {sub}"))
+    sub = sublane_for(dtype_bytes)
+    if plan.wo_pad // blk.x > 1 or blk.x % sub:
+        diags.append(Diagnostic(
+            rule="mosaic.xtile", severity=_mosaic_sev(target),
+            where=where,
+            message=f"x tile {blk.x} is not one {sub}-row multiple "
+                    f"spanning the padded output row {plan.wo_pad}",
+            hint=f"make the x tile round_up(wo, {sub}); the kernel "
+                 f"crops the pad"))
+    strided = sy * sx * plan.lhs_dilation[0] * plan.lhs_dilation[1] > 1
+    if strided and blk.ci > LANE:
+        diags.append(Diagnostic(
+            rule="mosaic.stride", severity=_mosaic_sev(target),
+            where=where, message=f"strided access over a {blk.ci}-wide "
+            f"input block", hint=f"cap ci_block at {LANE}"))
+    if target == TARGET_MOSAIC:
+        vmem, tile = plan.mosaic_working_set(dtype_bytes)
+        if vmem > budget:
+            diags.append(_err(
+                "mosaic.vmem", f"compiled working set {vmem} B exceeds "
+                f"the {budget} B scoped VMEM limit",
+                hint="shrink the batch/y blocks, then co", where=where))
+        if tile > MOSAIC_TILE_BYTES:
+            diags.append(_err(
+                "mosaic.tile", f"in-kernel tile of {tile} B exceeds "
+                f"{MOSAIC_TILE_BYTES} B", hint="shrink the batch/y "
+                "blocks", where=where))
     if blk.ci < min(MXU_DIM, plan.ci_pad):
         diags.append(Diagnostic(
             rule="mosaic.mxu", severity=WARN, where=where,
@@ -360,7 +380,7 @@ def check_wgrad_plan(wplan, *, batch: int = 1, dtype_bytes: int = 4,
     executes.  Under the ``mosaic`` target the streamed panels also
     obey the lane tiling rules (the kernel's last dims are the
     channel blocks)."""
-    budget = VMEM_BYTES // 2 if vmem_budget is None else vmem_budget
+    budget = VMEM_LIMIT_BYTES if vmem_budget is None else vmem_budget
     diags: list[Diagnostic] = []
     for name, b, dim in (("ci_b", wplan.ci_b, wplan.ci),
                          ("co_b", wplan.co_b, wplan.co),
@@ -394,6 +414,30 @@ def check_wgrad_plan(wplan, *, batch: int = 1, dtype_bytes: int = 4,
             f"> {budget} B budget",
             hint="shrink the strip first, then the channel blocks",
             where=where))
+    if target == TARGET_MOSAIC:
+        if wplan.sy * wplan.sx > 1 and wplan.ci_b > LANE:
+            diags.append(_err(
+                "mosaic.stride", f"strided window reads over a "
+                f"{wplan.ci_b}-wide x slab", hint=f"cap ci_b at {LANE}",
+                where=where))
+        sub = sublane_for(dtype_bytes)
+        if wplan.wo_pad % sub:
+            diags.append(_err(
+                "mosaic.xtile", f"dy strip width {wplan.wo_pad} is not "
+                f"a {sub}-row multiple", hint=f"align the dy width to "
+                f"{sub}", where=where))
+        vmem, tile = wplan.mosaic_working_set(dtype_bytes)
+        if vmem > budget:
+            diags.append(_err(
+                "mosaic.vmem", f"compiled working set {vmem} B exceeds "
+                f"the {budget} B scoped VMEM limit",
+                hint="shrink the strip, then the channel blocks",
+                where=where))
+        if tile > MOSAIC_TILE_BYTES:
+            diags.append(_err(
+                "mosaic.tile", f"in-kernel tile of {tile} B exceeds "
+                f"{MOSAIC_TILE_BYTES} B", hint="shrink the strip",
+                where=where))
     ci_pad = ceil_div(wplan.ci, wplan.ci_b) * wplan.ci_b
     co_pad = ceil_div(wplan.co, wplan.co_b) * wplan.co_b
     for d in (_lane_rule(wplan.ci_b, ci_pad, "x strip panel", target,
@@ -418,7 +462,7 @@ def check_matmul_block(blk, m: int, n: int, k: int, *,
     through the *same* rule implementations the conv pass uses, so the
     matmul/attention kernels inherit the gate rather than growing a
     conv-only checker."""
-    budget = VMEM_BYTES // 2 if vmem_budget is None else vmem_budget
+    budget = VMEM_LIMIT_BYTES if vmem_budget is None else vmem_budget
     diags: list[Diagnostic] = []
     for name, b in (("bm", blk.bm), ("bn", blk.bn), ("bk", blk.bk)):
         if b < 1:
@@ -522,10 +566,14 @@ def symbolic_wgrad_traffic(wplan, batch: int) -> Traffic:
     r_rows = wplan.strip * wplan.sy
     k_rows = max(0, wplan.ekh - wplan.sy)
     lag = -(-k_rows // r_rows) if k_rows > 0 else 0
+    # dy strips are x_align-padded; x fetches widen to the last window
+    wo = round_up(wplan.wo, wplan.x_align)
+    wx = max(wplan.wp, (wplan.wk - 1) * wplan.dlx + (wo - 1) * wplan.sx
+             + 1)
     reads_x = (nci * nco * batch * (ns + lag)
-               * r_rows * wplan.wp * wplan.ci_b)
+               * r_rows * wx * wplan.ci_b)
     reads_dy = (nci * nco * batch * ns
-                * wplan.strip * wplan.wo * wplan.co_b)
+                * wplan.strip * wo * wplan.co_b)
     writes = (wplan.hk * wplan.wk) * (nci * wplan.ci_b) * (nco
                                                            * wplan.co_b)
     return Traffic(reads_in=float(reads_x), reads_w=float(reads_dy),
@@ -650,10 +698,11 @@ def _audit_conv(name, layer, plan, *, batch, dtype_bytes, vmem_budget,
                           words=acct.total, bound=bound)
 
 
-def _audit_wgrad(name, wplan, *, batch, dtype_bytes,
-                 vmem_budget) -> PlanAuditEntry:
+def _audit_wgrad(name, wplan, *, batch, dtype_bytes, vmem_budget,
+                 target) -> PlanAuditEntry:
     diags = check_wgrad_plan(wplan, dtype_bytes=dtype_bytes,
-                             vmem_budget=vmem_budget, where=name)
+                             vmem_budget=vmem_budget, target=target,
+                             where=name)
     acct = wplan.traffic(batch)
     traffic_ok = _traffic_eq(symbolic_wgrad_traffic(wplan, batch), acct)
     return PlanAuditEntry(name=name, diagnostics=tuple(diags),
@@ -683,7 +732,8 @@ def audit_handles(handles, *, batch: int, dtype_bytes: int = 4,
                 target=target))
             entries.append(_audit_wgrad(
                 f"{layer.name}/wgrad", handle.wgrad, batch=batch,
-                dtype_bytes=dtype_bytes, vmem_budget=vmem_budget))
+                dtype_bytes=dtype_bytes, vmem_budget=vmem_budget,
+                target=target))
         else:
             entries.append(_audit_conv(
                 f"{layer.name}/fwd", layer, handle, batch=batch,
@@ -704,6 +754,6 @@ def audit_graph(graph, h: int, w: int, *, batch: int, in_ch: int = 3,
     handles = graph_plan_handles(graph, h, w, batch=batch, in_ch=in_ch,
                                  dtype_bytes=dtype_bytes,
                                  vmem_budget=vmem_budget,
-                                 training=training)
+                                 training=training, target=target)
     return audit_handles(handles, batch=batch, dtype_bytes=dtype_bytes,
                          vmem_budget=vmem_budget, target=target)

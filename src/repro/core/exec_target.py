@@ -29,13 +29,15 @@ The four targets, ordered by capability (``rank``):
   ACCOUNT_ONLY interpret —           False     False
   ======== ============ =========== ========= ==========
 
-``COMPILED`` runs ``pallas_call(interpret=False)``: Mosaic on TPU;
-where no TPU is attached, :mod:`repro.kernels.pallas_cpu` registers a
-CPU lowering that compiles the kernel's grid schedule to straight-line
-XLA, so compiled-mode wall clocks are measurable on any host.  A
-COMPILED request whose plan has no mosaic-legal shape falls back
-per-layer to LAX with a traced ``exec.fallback`` event — never
-silently to the interpreter.
+``COMPILED`` means Mosaic: ``pallas_call(interpret=False)`` compiled
+for the TPU.  Requesting it where no TPU is attached raises (Pallas
+refuses a non-interpret call on the CPU backend); it never runs
+something else.  CPU runs use ``INTERPRET`` (the Pallas interpreter)
+or ``LAX``, and the test suite compiles the Mosaic kernels ahead of
+time for a described v5e.  A COMPILED request whose plan has no
+mosaic-legal shape falls back per-layer to LAX with a traced
+``exec.fallback`` event and a tally (``exec_fallback_counts``) —
+never silently to the interpreter.
 
 Downward-only override negotiation is centralized in :meth:`clamp`:
 ``server_target.clamp(request_target)`` returns the *lower-ranked* of

@@ -24,7 +24,17 @@ import itertools
 PEAK_BF16_FLOPS = 197e12          # MXU bf16
 HBM_BYTES_PER_S = 819e9
 ICI_BYTES_PER_S = 50e9            # per link
-VMEM_BYTES = 128 * 1024 * 1024    # v5e VMEM per core
+VMEM_BYTES = 128 * 1024 * 1024    # v5e VMEM per core (physical)
+# scoped VMEM one kernel asks Mosaic for (``vmem_limit_bytes``) and the
+# execution planner's default budget — one number, so a plan that fits
+# the budget is a kernel Mosaic grants.  Mosaic's own default scope on
+# v5e is 16 MiB; the physical 128 MiB also holds Mosaic's internal
+# scratch and the in-kernel temporaries the mosaic profile charges
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+# cap on one in-kernel value under the mosaic profile: Mosaic unrolls
+# vector code over the tile, so a 1 MiB tile keeps each kernel's
+# compile near a second while still feeding the MXU 1-2k-row matmuls
+MOSAIC_TILE_BYTES = 1024 * 1024
 HBM_BYTES = 16 * 1024 * 1024 * 1024
 MXU_DIM = 128                     # systolic array edge
 LANE = 128                        # last-dim tile
@@ -71,7 +81,7 @@ class BlockShape:
 def lb_block_shape(m: int, n: int, k: int, *,
                    r: float = 1.0,
                    dtype_bytes: int = 2,
-                   vmem_budget: int = VMEM_BYTES // 2,
+                   vmem_budget: int = VMEM_LIMIT_BYTES,
                    bk: int | None = None,
                    align: int = MXU_DIM) -> BlockShape:
     """Choose {bm, bn, bk} from the paper's lower-bound conditions.
@@ -162,6 +172,41 @@ class ConvBlockShape:
                 + self.b * self.halo_y * self.halo_x * self.ci
                 + hk * wk * self.ci * self.co)
 
+    def mosaic_vmem_bytes(self, hk: int, wk: int, dtype_bytes: int = 4,
+                          *, fetch: tuple[int, int], pool: int = 1,
+                          residual: bool = False,
+                          dilated: tuple[int, int] | None = None) -> int:
+        """VMEM the compiled kernel really holds: every block Mosaic
+        double-buffers (input fetch of ``fetch`` rows x cols, weights,
+        residual, output) plus the f32 psum scratch, the lhs-dilation
+        scratch of ``dilated`` rows x cols, and the window-sweep
+        temporaries (one input slice, the dot result and the psum
+        reload), each laid out on (sublane, LANE) tiles."""
+        db = dtype_bytes
+        s = sublane_for(db)
+        ci, co = round_up(self.ci, LANE), round_up(self.co, LANE)
+        row = self.b * self.y * round_up(self.x, s)      # psum rows
+        out = self.b * (self.y // pool) * round_up(self.x // pool, s)
+        n = (2 * self.b * fetch[0] * round_up(fetch[1], s) * ci * db
+             + 2 * hk * wk * round_up(self.ci, s) * co * db
+             + 2 * out * co * db
+             + 3 * row * co * 4 + row * ci * db)
+        if residual:
+            n += 2 * row * co * db
+        if pool > 1:                                  # pool scratch
+            n += self.b * (self.y // pool) * round_up(self.x, s) * LANE * 4
+        if dilated is not None:
+            n += self.b * dilated[0] * round_up(dilated[1], s) * ci * db
+        return n
+
+    def mosaic_tile_bytes(self, dtype_bytes: int = 4) -> int:
+        """Largest single in-kernel value (the f32 psum tile or one
+        window slice) on (sublane, LANE) tiles — what Mosaic unrolls
+        over vector registers, so its compile time scales with it."""
+        rows = self.b * self.y * round_up(self.x, sublane_for(dtype_bytes))
+        return rows * max(round_up(self.co, LANE) * 4,
+                          round_up(self.ci, LANE) * dtype_bytes)
+
 
 def balanced_tile(dim: int, t: int) -> int:
     """Largest tile <= t splitting dim into equal ceil pieces —
@@ -175,7 +220,7 @@ def conv_lb_block_shape(ho: int, wo: int, ci: int, co: int,
                         stride: tuple[int, int] = (1, 1),
                         dilation: tuple[int, int] = (1, 1),
                         dtype_bytes: int = 4,
-                        vmem_budget: int = VMEM_BYTES // 2
+                        vmem_budget: int = VMEM_LIMIT_BYTES
                         ) -> ConvBlockShape:
     """Spatially-tiled conv blocks from the paper's two key conditions.
 

@@ -77,9 +77,6 @@ def attention_call(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              bk=bk, seq_kv=seq_kv, window=window,
                              causal=causal)
     g = groups
-    if not interpret and jax.default_backend() == "cpu":
-        from repro.kernels.pallas_cpu import ensure_compiled_cpu
-        ensure_compiled_cpu()
     return pl.pallas_call(
         kern,
         grid=(bh, nq, nk),

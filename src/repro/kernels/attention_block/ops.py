@@ -66,17 +66,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bk = min(bk, max(8, skv))
     pad_q = -sq % bq
     pad_k = -skv % bk
-    if tgt is not None and not tgt.interpret \
-            and jax.default_backend() == "cpu":
-        from repro.kernels.pallas_cpu import COMPILED_MAX_GRID_STEPS
-        steps = (b * h) * ((sq + pad_q) // bq) * ((skv + pad_k) // bk)
-        if steps > COMPILED_MAX_GRID_STEPS:
-            active_tracer().event(
-                "exec.fallback", target=tgt.name, to="lax",
-                layer=f"attn b{b}s{sq}h{h}d{hd}",
-                reason=f"grid of {steps} steps exceeds the unrolled "
-                       f"CPU lowering budget")
-            return _lax_attention(q, k, v, window=window, causal=causal)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(b * kv, skv, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(b * kv, skv, hd)

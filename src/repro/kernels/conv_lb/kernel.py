@@ -14,11 +14,11 @@ Per grid step:
     whole Ci sweep (OutR: psums never touch HBM, every output is
     written exactly once);
   * a Ci-slice of the *halo-extended* input tile for all ``bb`` images
-    is streamed in through an overlapping ``pl.Unblocked`` BlockSpec —
-    neighbouring spatial tiles re-read only the (Wk-1)/(Hk-1) halo
-    rows/cols, and all Wk*Hk shifted windows are served from the one
-    VMEM-resident tile (WndR on chip); batch rows add u without adding
-    halo;
+    is streamed in through an overlapping, element-indexed
+    (``pl.Element``) BlockSpec — neighbouring spatial tiles re-read
+    only the (Wk-1)/(Hk-1) halo rows/cols, and all Wk*Hk shifted
+    windows are strided loads from the one VMEM-resident tile (WndR on
+    chip); batch rows add u without adding halo;
   * the matching z-kernel weight slice is streamed **once per u x z
     block regardless of bb** — ``reads_w`` stops scaling with batch:
     folding b images into one block divides the weight traffic of the
@@ -31,7 +31,9 @@ are folded into the in-VMEM strided slice, so WndR survives both.
 
 Fused epilogue (applied inside the flush step, while the psum tile is
 still in VMEM): optional ``bias`` add, ``relu``, and an aligned
-``pool`` x ``pool`` max-pool (stride = pool, VALID).  This collapses a
+``pool`` x ``pool`` max-pool (stride = pool, VALID: a leading-dim
+split for the rows, strided loads through a lane-wide scratch for the
+columns).  This collapses a
 CNN layer's ``conv-write -> read -> bias/relu/pool -> write`` HBM round
 trip into the single mandatory output write — with pooling the write
 volume itself drops by pool**2.
@@ -53,8 +55,9 @@ transposed-conv geometry: the *logical* input plane is the forward
 stride's zero-dilation of a compact plane (``stride-1`` zeros between
 rows/cols), but HBM only ever holds the compact plane.  The BlockSpec
 walks the compact plane — each tile fetches the ``ceil``-shrunk halo —
-and the kernel re-inserts the zeros in VMEM with one interior-padding
-``lax.pad`` before the window sweep, so the dilated tile is
+and the kernel re-inserts the zeros in VMEM — one strided store of
+the fetch into a zeroed scratch — before the window sweep, so the
+dilated tile is
 materialized on chip from a compact fetch: traffic scales with the
 compact (true dy) plane, not the dilated one.  Phase contract: the
 per-tile input offset ``y_block*stride_y`` must divide by the lhs
@@ -72,6 +75,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.tpu_adapter import LANE, VMEM_LIMIT_BYTES
 
 
 def halo_dims(y_block: int, x_block: int, hk: int, wk: int,
@@ -117,7 +122,7 @@ def _conv_kernel(*refs, nci: int, hk: int, wk: int,
                  bb: int, ty: int, tx: int,
                  stride: tuple[int, int], dilation: tuple[int, int],
                  lhs_dilation: tuple[int, int],
-                 off: tuple[int, int], hi_pad: tuple[int, int],
+                 off: tuple[int, int], chalo: tuple[int, int],
                  has_bias: bool, has_residual: bool, relu: bool,
                  pool: int):
     refs = list(refs)
@@ -125,7 +130,7 @@ def _conv_kernel(*refs, nci: int, hk: int, wk: int,
     rest = refs[2:]
     b_ref = rest.pop(0) if has_bias else None
     r_ref = rest.pop(0) if has_residual else None
-    o_ref, acc_ref = rest
+    o_ref, acc_ref = rest[:2]
 
     @pl.when(pl.program_id(4) == 0)
     def _init():
@@ -137,23 +142,20 @@ def _conv_kernel(*refs, nci: int, hk: int, wk: int,
     offy, offx = off
     cib = x_ref.shape[-1]
     cob = acc_ref.shape[-1]
-    xt = x_ref[...]
+    src = x_ref
     if (ldy, ldx) != (1, 1):
-        # compact fetch -> dilated VMEM tile: one interior-padding
-        # lax.pad re-inserts the stride-1 zero rows/cols (plus a short
-        # hi edge so the last window's slice stays in bounds)
-        xt = jax.lax.pad(
-            xt, jnp.array(0, xt.dtype),
-            ((0, 0, 0), (0, hi_pad[0], ldy - 1),
-             (0, hi_pad[1], ldx - 1), (0, 0, 0)))
+        # compact fetch -> dilated VMEM tile: one strided store places
+        # the compact rows/cols on the dilation grid of a zeroed
+        # scratch (the stride-1 zero rows/cols, plus a short hi edge so
+        # the last window's slice stays in bounds)
+        src = rest[2]
+        src[...] = jnp.zeros_like(src)
+        src[:, pl.ds(0, chalo[0], stride=ldy),
+            pl.ds(0, chalo[1], stride=ldx), :] = x_ref[...]
     for ky in range(hk):                      # unrolled window sweep:
         for kx in range(wk):                  # WndR served from VMEM
-            xs = jax.lax.slice(
-                xt,
-                (0, offy + ky * dy, offx + kx * dx, 0),
-                (bb, offy + ky * dy + (ty - 1) * sy + 1,
-                 offx + kx * dx + (tx - 1) * sx + 1, cib),
-                (1, sy, sx, 1))               # (bb, ty, tx, cib)
+            xs = src[:, pl.ds(offy + ky * dy, ty, stride=sy),
+                     pl.ds(offx + kx * dx, tx, stride=sx), :]
             acc_ref[...] += jnp.dot(
                 xs.reshape(bb * ty * tx, cib), w_ref[ky, kx],
                 preferred_element_type=jnp.float32
@@ -169,8 +171,23 @@ def _conv_kernel(*refs, nci: int, hk: int, wk: int,
         if relu:
             acc = jnp.maximum(acc, 0.0)
         if pool > 1:
-            acc = acc.reshape(bb, ty // pool, pool,
-                              tx // pool, pool, cob).max(axis=(2, 4))
+            # rows: max over a leading-dim split of the finished tile
+            acc = acc.reshape(bb, ty // pool, pool, tx, cob)
+            acc = functools.reduce(jnp.maximum,
+                                   [acc[:, :, i] for i in range(pool)])
+            # cols: strided sublane reads through a scratch, one
+            # LANE-wide channel chunk at a time (Mosaic's strided load
+            # takes at most one lane tile as its base's last dim)
+            pool_ref = rest[-1]
+            lanes = pool_ref.shape[-1]
+            parts = []
+            for c in range(cob // lanes):
+                pool_ref[...] = acc[..., c * lanes:(c + 1) * lanes]
+                parts.append(functools.reduce(jnp.maximum, [
+                    pool_ref[:, :, pl.ds(j, tx // pool, stride=pool), :]
+                    for j in range(pool)]))
+            acc = (parts[0] if len(parts) == 1
+                   else jnp.concatenate(parts, axis=-1))
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -225,10 +242,10 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
                                               pad[0])
     chalo_x, step_x, offx = compact_axis_dims(x_block, xp, sx, ldx,
                                               pad[1])
-    # rows of the reconstructed tile after interior padding, extended
-    # hi so the deepest window slice (off + halo rows) stays in bounds
-    hi_y = max(0, offy + yp - ((chalo_y - 1) * ldy + 1))
-    hi_x = max(0, offx + xp - ((chalo_x - 1) * ldx + 1))
+    # rows of the reconstructed dilated tile, extended hi so the
+    # deepest window slice (off + halo rows) stays in bounds
+    rec_y = max(offy + yp, (chalo_y - 1) * ldy + 1)
+    rec_x = max(offx + xp, (chalo_x - 1) * ldx + 1)
     if lhs_dilated:
         assert hp >= (ny - 1) * step_y + chalo_y, (hp, ny, step_y,
                                                    chalo_y)
@@ -238,28 +255,27 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
     if residual is not None:
         assert residual.shape == (b, ho, wo, co), (residual.shape,
                                                    (b, ho, wo, co))
-    if not interpret and jax.default_backend() == "cpu":
-        # no TPU attached: compiled mode runs through the straight-line
-        # XLA lowering instead of raising "interpret only on CPU"
-        from repro.kernels.pallas_cpu import ensure_compiled_cpu
-        ensure_compiled_cpu()
     kern = functools.partial(_conv_kernel, nci=nci, hk=hk, wk=wk,
                              bb=b_block, ty=y_block, tx=x_block,
                              stride=stride, dilation=dilation,
                              lhs_dilation=lhs_dilation,
-                             off=(offy, offx), hi_pad=(hi_y, hi_x),
+                             off=(offy, offx), chalo=(chalo_y, chalo_x),
                              has_bias=bias is not None,
                              has_residual=residual is not None,
                              relu=relu, pool=pool)
     in_specs = [
-        # overlapping halo tile: element offsets, not block indices —
-        # an lhs-dilated walk strides the compact plane instead
+        # overlapping halo tile: element offsets, not block indices
+        # (Mosaic takes Element on every dim or none) — an
+        # lhs-dilated walk strides the compact plane instead
         pl.BlockSpec(
-            (b_block, chalo_y, chalo_x, ci_block),
+            (pl.Element(b_block), pl.Element(chalo_y),
+             pl.Element(chalo_x), pl.Element(ci_block)),
             lambda bi, yi, xi, coi, cii: (
-                bi * b_block, yi * step_y, xi * step_x,
-                cii * ci_block),
-            indexing_mode=pl.Unblocked()),
+                bi * b_block, yi * step_y,
+                # a sole x tile / Ci block starts at literal 0, so
+                # Mosaic can prove the tiled dims' offsets aligned
+                0 if nx == 1 else xi * step_x,
+                0 if nci == 1 else cii * ci_block)),
         pl.BlockSpec((hk, wk, ci_block, co_block),
                      lambda bi, yi, xi, coi, cii: (0, 0, cii, coi)),
     ]
@@ -275,6 +291,15 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
             (b_block, y_block, x_block, co_block),
             lambda bi, yi, xi, coi, cii: (bi, yi, xi, coi)))
         operands.append(residual)
+    scratch = [pltpu.VMEM((b_block, y_block, x_block, co_block),
+                          jnp.float32)]
+    if lhs_dilated:
+        scratch.append(pltpu.VMEM((b_block, rec_y, rec_x, ci_block),
+                                  x.dtype))
+    if pool > 1:
+        lanes = co_block if co_block % LANE else LANE
+        scratch.append(pltpu.VMEM((b_block, y_block // pool, x_block,
+                                   lanes), jnp.float32))
     return pl.pallas_call(
         kern,
         grid=(nb, ny, nx, nco, nci),
@@ -284,7 +309,8 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
             lambda bi, yi, xi, coi, cii: (bi, yi, xi, coi)),
         out_shape=jax.ShapeDtypeStruct(
             (b, ho // pool, wo // pool, co), out_dtype),
-        scratch_shapes=[pltpu.VMEM((b_block, y_block, x_block, co_block),
-                                   jnp.float32)],
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)

@@ -66,6 +66,7 @@ from __future__ import annotations
 import dataclasses
 from functools import lru_cache, partial
 from math import gcd as _gcd
+from math import lcm as _lcm
 
 import jax
 import jax.numpy as jnp
@@ -73,8 +74,9 @@ import jax.numpy as jnp
 from repro.core.dataflow import Traffic
 from repro.core.exec_target import resolve_target
 from repro.core.layer import ceil_div
-from repro.core.tpu_adapter import (VMEM_BYTES, ConvBlockShape,
-                                    balanced_tile, conv_block_candidates,
+from repro.core.tpu_adapter import (MOSAIC_TILE_BYTES, VMEM_LIMIT_BYTES,
+                                    ConvBlockShape, balanced_tile,
+                                    conv_block_candidates,
                                     conv_lb_block_shape, round_up)
 from repro.kernels.conv_lb.wgrad import wgrad_lb_call
 from repro.obs.tracer import active_tracer
@@ -82,6 +84,30 @@ from repro.obs.tracer import active_tracer
 
 def _pair(v) -> tuple[int, int]:
     return tuple(v) if isinstance(v, (tuple, list)) else (int(v), int(v))
+
+
+def mosaic_working_set(blk: ConvBlockShape, hk: int, wk: int,
+                       dtype_bytes: int, *, stride, dilation,
+                       lhs_dilation=(1, 1), pad=(0, 0), pool: int = 1,
+                       residual: bool = False) -> tuple[int, int]:
+    """``(vmem_bytes, tile_bytes)`` of the compiled kernel for ``blk``:
+    :meth:`ConvBlockShape.mosaic_vmem_bytes` at the fetch the BlockSpec
+    really makes (the compact halo of an lhs-dilated walk, plus its
+    reconstructed dilated scratch) and the largest in-kernel value."""
+    from repro.kernels.conv_lb.kernel import compact_halo
+
+    fetch, rec = [], []
+    for halo, ld, p in ((blk.halo_y, lhs_dilation[0], pad[0]),
+                        (blk.halo_x, lhs_dilation[1], pad[1])):
+        chalo = compact_halo(halo, ld, p)
+        off = ceil_div(p, ld) * ld - p if ld > 1 else 0
+        fetch.append(chalo)
+        rec.append(max(off + halo, (chalo - 1) * ld + 1))
+    dilated = tuple(rec) if tuple(lhs_dilation) != (1, 1) else None
+    vmem = blk.mosaic_vmem_bytes(hk, wk, dtype_bytes, fetch=tuple(fetch),
+                                 pool=pool, residual=residual,
+                                 dilated=dilated)
+    return vmem, blk.mosaic_tile_bytes(dtype_bytes)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,6 +219,15 @@ class ConvPlan:
         return self.blocks.footprint_elems(self.hk, self.wk,
                                            residual=self.residual)
 
+    def mosaic_working_set(self, dtype_bytes: int = 4) -> tuple[int, int]:
+        """``(vmem_bytes, tile_bytes)`` of the compiled kernel for this
+        plan (see :func:`mosaic_working_set`)."""
+        return mosaic_working_set(
+            self.blocks, self.hk, self.wk, dtype_bytes,
+            stride=self.stride, dilation=self.dilation,
+            lhs_dilation=self.lhs_dilation, pad=(self.py, self.px),
+            pool=self.pool, residual=self.residual)
+
     def bound_words(self, layer) -> float:
         """This layer's Eq. (15) bound at the realized plan footprint,
         plus the residual join's mandatory once-per-word read when the
@@ -228,14 +263,15 @@ class ConvPlan:
         rejected or a ratio looks wrong."""
         from repro.analysis.plan_check import (check_conv_plan,
                                                format_diagnostics)
-        from repro.core.tpu_adapter import VMEM_BYTES as _VMEM
-
         target = self.target if target is None else target
-        budget = _VMEM // 2 if vmem_budget is None else vmem_budget
+        budget = VMEM_LIMIT_BYTES if vmem_budget is None else vmem_budget
         blk = self.blocks
         pinned = blk.ci >= self.ci_pad and blk.co >= self.co_pad
-        need = blk.vmem_bytes(self.hk, self.wk, dtype_bytes,
-                              w_pinned=pinned, residual=self.residual)
+        need = (self.mosaic_working_set(dtype_bytes)[0]
+                if target == "mosaic"
+                else blk.vmem_bytes(self.hk, self.wk, dtype_bytes,
+                                    w_pinned=pinned,
+                                    residual=self.residual))
         t = self.traffic(batch)
         ny, nx, nco, nci = self.grid
         diags = check_conv_plan(self, batch=batch,
@@ -409,39 +445,52 @@ def autotune_conv_blocks(batch: int, ho: int, wo: int, ci: int, co: int,
                                residual=residual,
                                lhs_dilation=lhs_dilation, pad=pad)
 
+    # the mosaic x tile: the whole output row, padded to a sublane
+    # (and pool / lhs-phase) multiple — the kernel's (b*y*x, ci) merge
+    # needs x % sublane == 0, and a full-width halo fetch is the only
+    # element-indexed input block Mosaic tiles
+    x_row = round_up(wo, _lcm(sub, p, ldx // _gcd(ldx, sx)))
+    # a strided window read (or lhs-dilation store) is a Mosaic strided
+    # access, whose base block may span at most one lane tile
+    ci_cap = LANE if sy * sx * ldy * ldx > 1 else ci
+
     def fits(blk: ConvBlockShape) -> bool:
+        if mosaic:
+            vmem, tile = mosaic_working_set(
+                blk, hk, wk, db, stride=stride, dilation=dilation,
+                lhs_dilation=lhs_dilation, pad=pad, pool=pool,
+                residual=residual)
+            return vmem <= vmem_budget and tile <= MOSAIC_TILE_BYTES
         pinned = blk.ci >= ci and blk.co >= co
         return blk.vmem_bytes(hk, wk, db, w_pinned=pinned,
                               residual=residual) <= vmem_budget
 
     def mosaic_ok(blk: ConvBlockShape) -> bool:
         ci_pad, co_pad = round_up(ci, blk.ci), round_up(co, blk.co)
-        nx = round_up(wo, blk.x) // blk.x
         return ((blk.ci % LANE == 0 or blk.ci >= ci_pad)
                 and (blk.co % LANE == 0 or blk.co >= co_pad)
-                and (nx == 1 or ((blk.x // p) % sub == 0
-                                 and (blk.x * sx) % sub == 0)))
+                and blk.x == x_row and blk.ci <= ci_cap)
 
     def snap_ch(v: int, dim: int) -> int:
         """Nearest legal channel block: a LANE multiple, or full."""
         return dim if v >= dim or round_up(v, LANE) >= dim \
             else round_up(v, LANE)
 
-    def snap_x(v: int) -> int:
-        """Nearest legal spatial x block: sublane-aligned pooled rows
-        and sublane-aligned unblocked offsets, or the full plane."""
-        v = round_up(v, sub * p)
-        return v if v < wo else _snap_pool(wo, wo, pool)
+    def lane_blocks(dim: int) -> list[int]:
+        """Legal channel blocks, largest first: full, then LANE
+        multiples below it."""
+        return [dim] + list(range((dim - 1) // LANE * LANE, 0, -LANE))
 
     def snap_mosaic(blk: ConvBlockShape) -> ConvBlockShape:
-        cib, cob = snap_ch(blk.ci, ci), snap_ch(blk.co, co)
-        x = snap_x(blk.x)
+        cib = min(snap_ch(blk.ci, ci), ci_cap)
+        cob = snap_ch(blk.co, co)
+        x, y = x_row, snap_lhs(blk.y, ho, sy, ldy)
         if (cib, cob, x) != (blk.ci, blk.co, blk.x):
             note("autotune.mosaic",
                  f"snapped candidate ci={blk.ci} co={blk.co} "
                  f"x={blk.x} to Mosaic-legal ci={cib} co={cob} x={x}")
-        return ConvBlockShape(y=blk.y, x=x, co=cob, ci=cib,
-                              halo_y=(blk.y - 1) * sy + (hk - 1) * dy + 1,
+        return ConvBlockShape(y=y, x=x, co=cob, ci=cib,
+                              halo_y=(y - 1) * sy + (hk - 1) * dy + 1,
                               halo_x=(x - 1) * sx + (wk - 1) * dx + 1,
                               b=blk.b)
 
@@ -465,29 +514,27 @@ def autotune_conv_blocks(batch: int, ho: int, wo: int, ci: int, co: int,
     for b, y, x, cib in conv_block_candidates(batch, ho, wo, ci):
         y, x = _snap_pool(y, ho, pool), _snap_pool(x, wo, pool)
         if mosaic:
-            cib, x = snap_ch(cib, ci), snap_x(x)
+            cib, x = min(snap_ch(cib, ci), ci_cap), x_row
         y = snap_lhs(y, ho, sy, ldy)
         x = snap_lhs(x, wo, sx, ldx)
         yp = (y - 1) * sy + (hk - 1) * dy + 1
         xp = (x - 1) * sx + (wk - 1) * dx + 1
-        # largest co_b under the budget: psums 4*b*y*x*co_b plus
-        # double-buffered input (b*yp*xp*cib), weight (kk*cib*co_b)
-        # and, for a fused join, residual (b*y*x*co_b) panels
-        free = vmem_budget - 2 * db * b * yp * xp * cib
-        denom = (4 * b * y * x + 2 * db * kk * cib
-                 + (2 * db * b * y * x if residual else 0))
-        cobs = []
-        if free // denom >= 1:
-            cobs.append(min(co, int(free // denom)))
-        if cib >= ci:
-            cobs.append(co)         # weight-pinned: one fetch, 1x buffer
+        if mosaic:
+            cobs = lane_blocks(co)  # largest first: the first fit wins
+        else:
+            # largest co_b under the budget: psums 4*b*y*x*co_b plus
+            # double-buffered input (b*yp*xp*cib), weight
+            # (kk*cib*co_b) and, for a fused join, residual
+            # (b*y*x*co_b) panels
+            free = vmem_budget - 2 * db * b * yp * xp * cib
+            denom = (4 * b * y * x + 2 * db * kk * cib
+                     + (2 * db * b * y * x if residual else 0))
+            cobs = []
+            if free // denom >= 1:
+                cobs.append(balanced_tile(co, min(co, int(free // denom))))
+            if cib >= ci:
+                cobs.append(co)     # weight-pinned: one fetch, 1x buffer
         for cob in cobs:
-            if mosaic:
-                # floor to a LANE multiple (never exceed the analytic
-                # budget-max), keeping a full-co pin legal as-is
-                cob = co if cob >= co else ((cob // LANE) * LANE or cob)
-            else:
-                cob = balanced_tile(co, cob)
             blk = ConvBlockShape(y=y, x=x, co=cob, ci=cib,
                                  halo_y=yp, halo_x=xp, b=b)
             if blk in seen:
@@ -504,6 +551,8 @@ def autotune_conv_blocks(batch: int, ho: int, wo: int, ci: int, co: int,
                      f"no Mosaic-legal snap under the budget")
                 continue
             cands.append((traffic(blk), blk))
+            if mosaic:
+                break
     if not cands:
         raise PlanLegalityError([Diagnostic(
             rule="autotune.mosaic", severity="error",
@@ -511,9 +560,10 @@ def autotune_conv_blocks(batch: int, ho: int, wo: int, ci: int, co: int,
                     f"{vmem_budget} B budget for "
                     f"{ci}->{co} k{hk}x{wk} on {ho}x{wo}",
             hint="raise the VMEM budget or relax the target")])
+    # ties go to the larger psum tile under mosaic (fewer grid steps)
     best = min(cands,
-               key=lambda tb: (conv_plan_score(tb[0]),
-                               tb[0].reads_w))[1]
+               key=lambda tb: (conv_plan_score(tb[0]), tb[0].reads_w,
+                               -tb[1].u if mosaic else 0))[1]
     # nests under the plan.search span when a tracer is ambient
     active_tracer().event(
         "plan.autotune", candidates=len(cands),
@@ -573,7 +623,7 @@ def plan_conv(h: int, w: int, ci: int, co: int, hk: int, wk: int, *,
     if (ldy, ldx) != (1, 1) and (pool > 1 or residual):
         raise ValueError("lhs-dilated plans fuse no pool/residual "
                          "epilogue (dgrad/transposed convs have none)")
-    budget = VMEM_BYTES // 2 if vmem_budget is None else vmem_budget
+    budget = VMEM_LIMIT_BYTES if vmem_budget is None else vmem_budget
     auto = blocks is None
     if blocks is None:
         # fires only on LRU miss — a span per *distinct* geometry, via
@@ -597,7 +647,10 @@ def plan_conv(h: int, w: int, ci: int, co: int, hk: int, wk: int, *,
             _sp.set(blocks=f"b={blocks.b},y={blocks.y},x={blocks.x},"
                            f"ci={blocks.ci},co={blocks.co}")
     ty = _snap_pool(min(blocks.y, ho), ho, pool)
-    tx = _snap_pool(min(blocks.x, wo), wo, pool)
+    # a mosaic x tile is the whole row padded to a sublane multiple,
+    # so it may exceed wo (the kernel computes, then crops, the pad)
+    tx = (blocks.x if target == "mosaic" and blocks.x >= wo
+          else _snap_pool(min(blocks.x, wo), wo, pool))
     if ldy > 1 and (ty * sy) % ldy:
         # phase-snap: every compact fetch must start on a real row
         step = ldy // _gcd(ldy, sy)
@@ -681,7 +734,8 @@ def plan_conv_dgrad(plan: ConvPlan, *, batch: int = 1,
                               max(0, ekw - 1 - plan.px)),
                      dilation=plan.dilation,
                      lhs_dilation=(sy, sx), dtype_bytes=dtype_bytes,
-                     vmem_budget=vmem_budget, autotune=autotune)
+                     vmem_budget=vmem_budget, autotune=autotune,
+                     target=plan.target)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -743,10 +797,24 @@ class WgradPlan:
     py: int = 0        # fwd conv padding
     px: int = 0
     h: int = 0         # true input plane rows (0: unknown/legacy)
+    # dy strip width padded to this multiple (the mosaic profile's
+    # sublane: the kernel merges each (strip, wo) panel into rows)
+    x_align: int = 1
 
     @property
     def n_strips(self) -> int:
         return ceil_div(self.ho, self.strip)
+
+    @property
+    def wo_pad(self) -> int:
+        """dy cols after sublane alignment (zero-padded tail)."""
+        return round_up(self.wo, self.x_align)
+
+    @property
+    def wx(self) -> int:
+        """x plane cols fetched per strip: the padded input, widened so
+        the deepest window of the padded dy width stays in bounds."""
+        return max(self.wp, self.ekw + (self.wo_pad - 1) * self.sx)
 
     @property
     def lag(self) -> int:
@@ -783,8 +851,8 @@ class WgradPlan:
         nci, nco, _ = self.grid
         ci_pad = nci * self.ci_b
         co_pad = nco * self.co_b
-        reads_x = nco * batch * ci_pad * self._x_rows() * self.wp
-        reads_dy = nci * batch * co_pad * self.ho_pad * self.wo
+        reads_x = nco * batch * ci_pad * self._x_rows() * self.wx
+        reads_dy = nci * batch * co_pad * self.ho_pad * self.wo_pad
         writes = self.hk * self.wk * ci_pad * co_pad
         return Traffic(reads_in=float(reads_x), reads_w=float(reads_dy),
                        reads_out=0.0, writes_out=float(writes))
@@ -797,8 +865,31 @@ class WgradPlan:
         one x strip + one dy strip (no double buffering)."""
         xrows = (self.strip - 1) * self.sy + self.ekh
         return (self.hk * self.wk * self.ci_b * self.co_b
-                + xrows * self.wp * self.ci_b
-                + self.strip * self.wo * self.co_b)
+                + xrows * self.wx * self.ci_b
+                + self.strip * self.wo_pad * self.co_b)
+
+    def mosaic_working_set(self, dtype_bytes: int = 4) -> tuple[int, int]:
+        """``(vmem_bytes, tile_bytes)`` of the compiled wgrad kernel on
+        (sublane, LANE) tiles: the resident f32 dW block and its
+        double-buffered output, the (K + R)-row slab scratch, the
+        double-buffered x fetch and dy strip, and the window sweep's
+        slice (plus its transpose) and dy panel temporaries."""
+        from repro.core.tpu_adapter import LANE, sublane_for
+
+        db = dtype_bytes
+        sub = sublane_for(db)
+        ci, co = round_up(self.ci_b, LANE), round_up(self.co_b, LANE)
+        r_rows = self.strip * self.sy
+        k_rows = max(0, self.ekh - self.sy)
+        wx = round_up(self.wx, sub)
+        rows = self.strip * self.wo_pad
+        dw = 3 * self.hk * self.wk * round_up(self.ci_b, sub) * co * 4
+        vmem = (dw + (k_rows + 3 * r_rows) * wx * ci * db
+                + 2 * rows * co * db
+                + 2 * rows * ci * db + rows * co * db)
+        tile = max(rows * ci * db, rows * co * db, r_rows * wx * ci * db,
+                   round_up(self.ci_b, sub) * co * 4)   # one dW tap
+        return vmem, tile
 
 
 @lru_cache(maxsize=1024)
@@ -811,11 +902,19 @@ def plan_conv_wgrad(plan: ConvPlan, *, dtype_bytes: int = 4,
     (resident f32 dW block + double-buffered x/dy strips).  The plan
     carries no batch extent — like :class:`ConvPlan`, the same handle
     accounts any training batch via ``traffic(batch)``.  LRU-cached on
-    the (hashable) forward handle, like ``plan_conv``."""
-    from repro.core.layer import balanced_candidates
+    the (hashable) forward handle, like ``plan_conv``.
 
-    budget = VMEM_BYTES // 2 if vmem_budget is None else vmem_budget
+    A mosaic-target forward handle yields a mosaic plan: channel blocks
+    on LANE multiples (or the full dim), the dy width padded to the
+    sublane, and the fit judged by
+    :meth:`WgradPlan.mosaic_working_set` against the budget and
+    :data:`~repro.core.tpu_adapter.MOSAIC_TILE_BYTES`."""
+    from repro.core.layer import balanced_candidates
+    from repro.core.tpu_adapter import LANE, sublane_for
+
+    budget = VMEM_LIMIT_BYTES if vmem_budget is None else vmem_budget
     db = dtype_bytes
+    mosaic = plan.target == "mosaic"
     sy, sx = plan.stride
     ekh = (plan.hk - 1) * plan.dilation[0] + 1
     ekw = (plan.wk - 1) * plan.dilation[1] + 1
@@ -827,28 +926,45 @@ def plan_conv_wgrad(plan: ConvPlan, *, dtype_bytes: int = 4,
                          ci_b=cib, co_b=cob, strip=s,
                          sx=sx, ekw=ekw,
                          dly=plan.dilation[0], dlx=plan.dilation[1],
-                         py=plan.py, px=plan.px, h=plan.h)
+                         py=plan.py, px=plan.px, h=plan.h,
+                         x_align=sublane_for(db) if mosaic else 1)
 
-    def vmem_bytes(cib, cob, s):
-        xrows = (s - 1) * sy + ekh
-        return (4 * plan.hk * plan.wk * cib * cob     # f32 dW psums
-                + 2 * db * xrows * wp * cib           # double-buffered
-                + 2 * db * s * plan.wo * cob)         # streamed strips
+    def fits(cand):
+        if mosaic:
+            vmem, tile = cand.mosaic_working_set(db)
+            return vmem <= budget and tile <= MOSAIC_TILE_BYTES
+        xrows = (cand.strip - 1) * sy + ekh
+        return (4 * plan.hk * plan.wk * cand.ci_b * cand.co_b
+                + 2 * db * xrows * wp * cand.ci_b         # double-
+                + 2 * db * cand.strip * plan.wo * cand.co_b  # buffered
+                ) <= budget
 
-    ci_cands = balanced_candidates(plan.ci)
-    co_cands = balanced_candidates(plan.co)
+    def chans(dim, cap):
+        cands = balanced_candidates(dim)
+        if mosaic:
+            cands = [c for c in cands
+                     if (c == dim or c % LANE == 0) and c <= cap]
+        return cands
+
+    # strided window reads are Mosaic strided loads, whose base (the
+    # slab scratch) may span at most one lane tile
+    ci_cands = chans(plan.ci, LANE if sy * sx > 1 else plan.ci)
+    co_cands = chans(plan.co, plan.co)
     s_cands = balanced_candidates(plan.ho) if autotune else [1]
-    best = mk(1, 1, 1)      # minimal block: always the fallback
+    # minimal block: always the fallback
+    best = mk(min(ci_cands), min(co_cands), 1)
     best_cost = None
     for cib in ci_cands:
         for cob in co_cands:
             for s in s_cands:
-                if vmem_bytes(cib, cob, s) > budget:
-                    continue
                 cand = mk(cib, cob, s)
+                if not fits(cand):
+                    continue
                 # reads scale uniformly with batch and writes are
-                # batch-free, so ranking at batch=1 is batch-robust
-                cost = cand.traffic(1).total
+                # batch-free, so ranking at batch=1 is batch-robust; a
+                # compiled kernel breaks ties toward fewer grid steps
+                cost = (cand.traffic(1).total,
+                        -s if mosaic else 0)
                 if best_cost is None or cost < best_cost:
                     best, best_cost = cand, cost
     return best
@@ -962,6 +1078,12 @@ def _conv_one_group(x, w, bias, residual, plan: ConvPlan, py: int,
     else:
         x = jnp.pad(x, ((0, 0), (py, plan.hp_pad - x.shape[1] - py),
                         (px, plan.wp_pad - x.shape[2] - px), (0, 0)))
+        # drop trailing rows/cols no window reads (a strided conv's
+        # remainder): the last tile's halo then ends the plane, so a
+        # sole x tile's fetch spans the full width, as Mosaic requires
+        ny, nx = plan.ho_pad // blk.y, plan.wo_pad // blk.x
+        x = x[:, :(ny - 1) * blk.y * plan.stride[0] + blk.halo_y,
+              :(nx - 1) * blk.x * plan.stride[1] + blk.halo_x]
     x = _pad_axis(_pad_axis(x, 3, plan.ci_pad), 0, round_up(b, blk.b))
     w = _pad_axis(_pad_axis(w, 2, plan.ci_pad), 3, plan.co_pad)
     bias2d = None
@@ -1086,9 +1208,8 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
     the legacy ``interpret``/``fallback`` booleans: ``COMPILED`` plans
     at the mosaic legality profile and runs
     ``pallas_call(interpret=False)``; a geometry with no mosaic-legal
-    plan (or a grid too large for the unrolled CPU lowering) degrades
-    *loudly* to the lax path — a traced ``exec.fallback`` event, never
-    a silent interpreter run.  The backward pass inherits the target;
+    plan degrades *loudly* to the lax path — a traced
+    ``exec.fallback`` event, never a silent interpreter run.  The backward pass inherits the target;
     its dgrad conv re-negotiates per-layer (the dgrad geometry may be
     mosaic-legal when the forward is not, and vice versa).
 
@@ -1189,15 +1310,6 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
             if errors(diags):
                 return _loud_fallback(
                     "explicit blocks are not mosaic-legal")
-    if tgt is not None and not tgt.interpret \
-            and jax.default_backend() == "cpu":
-        from repro.kernels.pallas_cpu import (COMPILED_MAX_GRID_STEPS,
-                                              grid_steps)
-        steps = ceil_div(b, plan.blocks.b) * grid_steps(plan.grid)
-        if steps > COMPILED_MAX_GRID_STEPS:
-            return _loud_fallback(
-                f"grid of {steps} steps exceeds the unrolled CPU "
-                f"lowering budget ({COMPILED_MAX_GRID_STEPS})")
     co_g = co // groups
 
     def _run(x, w, bias, residual):
@@ -1297,16 +1409,6 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
         werrs = errors(check_wgrad_plan(wplan, batch=b,
                                         dtype_bytes=x.dtype.itemsize,
                                         target=plan_target))
-        wsteps = None
-        if tgt is not None and not tgt.interpret \
-                and jax.default_backend() == "cpu":
-            from repro.kernels.pallas_cpu import COMPILED_MAX_GRID_STEPS
-            nci_w, nco_w, ns_w = wplan.grid
-            wsteps = nci_w * nco_w * b * (ns_w + wplan.lag)
-            if wsteps > COMPILED_MAX_GRID_STEPS:
-                werrs = werrs or [
-                    f"grid of {wsteps} steps exceeds the unrolled CPU "
-                    f"lowering budget"]
         if werrs:
             gw = _wgrad_lax_fallback(x, w, gy, "; ".join(werrs))
         else:

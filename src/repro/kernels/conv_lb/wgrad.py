@@ -19,7 +19,9 @@ Rolling strips with a lagged carry: each grid step fetches a
 *disjoint* ``R = strip*stride``-row x block (every touched x row
 enters the chip exactly once per plane pass — the once-per-word
 claim WgradPlan charges), while the ``K = ekh - stride`` halo rows
-consecutive strips share live in a K-row carry scratch.  Because the
+consecutive strips share live at the front of a ``(K + R)``-row slab
+scratch (the carry, shifted there from the previous slab's tail,
+then the fetch behind it).  Because the
 halo of strip ``j`` extends *past* its own fetch, the compute lags the
 fetch by ``lag = ceil(K/R)`` steps: step ``si`` reduces dy strip
 ``j = si - lag`` against carry + fetch — rows ``[j*R, j*R + R + K)``
@@ -31,10 +33,10 @@ The dy strip BlockSpec indexes ``max(si - lag, 0)``: Pallas re-fetches
 only on index-map change, so each strip is fetched once per
 (ci-block, co-block, image) — the ``reads_dy`` the plan charges.
 
-Run under ``interpret=True`` (reference) or ``interpret=False`` via
-the ``pallas_cpu`` static-unroll lowering (scratch — the dW psums and
-the carry ring — threads across grid steps as loop carries there,
-which is exactly what this accumulation pattern needs).
+Runs under ``interpret=True`` (the CPU reference) or compiled by
+Mosaic on a TPU (``interpret=False``).  The window reads are strided
+loads from the slab scratch, so a strided layer's windows never
+materialize a value-level strided slice.
 """
 
 from __future__ import annotations
@@ -46,8 +48,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.tpu_adapter import VMEM_LIMIT_BYTES
 
-def _wgrad_kernel(x_ref, dy_ref, o_ref, acc_ref, carry_ref, *,
+
+def _wgrad_kernel(x_ref, dy_ref, o_ref, acc_ref, slab_ref, *,
                   ns: int, lag: int, k_rows: int, strip: int,
                   stride: tuple[int, int], dilation: tuple[int, int],
                   hk: int, wk: int, wo: int, nb: int):
@@ -63,27 +67,22 @@ def _wgrad_kernel(x_ref, dy_ref, o_ref, acc_ref, carry_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    fetch = x_ref[0]                          # (R, WX, cib), disjoint
+    # slab = carry ++ fetch: conv-padded rows [si*R - K, (si+1)*R); the
+    # carry is the previous slab's last K rows, shifted to the front
     if k_rows > 0:
-        # slab = carry ++ fetch: conv-padded rows [si*R - K, (si+1)*R)
-        slab = jnp.concatenate([carry_ref[...], fetch], axis=0)
-        carry_ref[...] = slab[r_rows:]        # keep the last K rows
-    else:
-        slab = fetch
+        slab_ref[:k_rows] = slab_ref[r_rows:]
+    slab_ref[k_rows:] = x_ref[0]              # (R, WX, cib), disjoint
 
     @pl.when(si >= lag)
     def _compute():                           # dy strip j = si - lag
         dys = dy_ref[0].reshape(strip * wo, cob)
         for ky in range(hk):                  # unrolled window sweep:
             for kx in range(wk):              # WndR served from VMEM
-                xs = jax.lax.slice(
-                    slab,
-                    (ky * dly, kx * dlx, 0),
-                    (ky * dly + (strip - 1) * sy + 1,
-                     kx * dlx + (wo - 1) * sx + 1, cib),
-                    (sy, sx, 1))              # (strip, wo, cib)
-                acc_ref[ky, kx] += jnp.dot(
-                    xs.reshape(strip * wo, cib).T, dys,
+                xs = slab_ref[pl.ds(ky * dly, strip, stride=sy),
+                              pl.ds(kx * dlx, wo, stride=sx), :]
+                acc_ref[ky, kx] += jax.lax.dot_general(
+                    xs.reshape(strip * wo, cib), dys,
+                    (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
 
     @pl.when((bi == nb - 1) & (si == ns + lag - 1))
@@ -108,10 +107,10 @@ def wgrad_lb_call(x: jax.Array, dy: jax.Array, wplan, *,
     k_rows = max(0, wplan.ekh - wplan.sy)
     assert lag * r_rows >= k_rows
     hx = (ns + lag) * r_rows                  # fetched plane rows
-    wx = wplan.wp
+    wx, wo_pad = wplan.wx, wplan.wo_pad
     # the deepest window column must stay inside the fetched width
     assert (wk_cols := (wplan.wk - 1) * wplan.dlx
-            + (wo - 1) * wplan.sx + 1) <= wx, (wk_cols, wx)
+            + (wo_pad - 1) * wplan.sx + 1) <= wx, (wk_cols, wx)
     ci_pad, co_pad = nci * wplan.ci_b, nco * wplan.co_b
 
     # shifted conv-padded x plane: P0 = lag*R - K alignment zeros, then
@@ -125,8 +124,9 @@ def wgrad_lb_call(x: jax.Array, dy: jax.Array, wplan, *,
                   (wplan.px, wx - w_in - wplan.px), (0, 0)))
     if ci_pad > ci:
         xp = jnp.pad(xp, ((0, 0), (0, 0), (0, 0), (0, ci_pad - ci)))
-    dyp = jnp.pad(dy, ((0, 0), (0, wplan.ho_pad - ho), (0, 0),
-                       (0, co_pad - co)))
+    # zero dy rows/cols past the plane contribute nothing to dW
+    dyp = jnp.pad(dy, ((0, 0), (0, wplan.ho_pad - ho),
+                       (0, wo_pad - wo), (0, co_pad - co)))
 
     # execution-site traffic: words moved by *this* call, derived from
     # the realized grid and operand block shapes (x's disjoint index
@@ -135,7 +135,8 @@ def wgrad_lb_call(x: jax.Array, dy: jax.Array, wplan, *,
     # measured side of the wgrad-vs-bound gate, independent of
     # WgradPlan.traffic
     moved = ((nci * nco * b) * ((ns + lag) * r_rows * wx * wplan.ci_b
-                                + ns * wplan.strip * wo * wplan.co_b)
+                                + ns * wplan.strip * wo_pad
+                                * wplan.co_b)
              + wplan.hk * wplan.wk * ci_pad * co_pad)
     from repro.obs.tracer import active_tracer
     active_tracer().event(
@@ -143,24 +144,21 @@ def wgrad_lb_call(x: jax.Array, dy: jax.Array, wplan, *,
         words_moved=moved, bytes_moved=moved * x.dtype.itemsize,
         interpret=interpret)
 
-    if not interpret and jax.default_backend() == "cpu":
-        from repro.kernels.pallas_cpu import ensure_compiled_cpu
-        ensure_compiled_cpu()
     kern = functools.partial(
         _wgrad_kernel, ns=ns, lag=lag, k_rows=k_rows,
         strip=wplan.strip, stride=(wplan.sy, wplan.sx),
         dilation=(wplan.dly, wplan.dlx),
-        hk=wplan.hk, wk=wplan.wk, wo=wo, nb=b)
+        hk=wplan.hk, wk=wplan.wk, wo=wo_pad, nb=b)
     scratch = [pltpu.VMEM((wplan.hk, wplan.wk, wplan.ci_b, wplan.co_b),
                           jnp.float32),
-               pltpu.VMEM((max(1, k_rows), wx, wplan.ci_b), xp.dtype)]
+               pltpu.VMEM((k_rows + r_rows, wx, wplan.ci_b), xp.dtype)]
     return pl.pallas_call(
         kern,
         grid=(nci, nco, b, ns + lag),
         in_specs=[
             pl.BlockSpec((1, r_rows, wx, wplan.ci_b),
                          lambda cii, coi, bi, si: (bi, si, 0, cii)),
-            pl.BlockSpec((1, wplan.strip, wo, wplan.co_b),
+            pl.BlockSpec((1, wplan.strip, wo_pad, wplan.co_b),
                          lambda cii, coi, bi, si:
                          (bi, jnp.maximum(si - lag, 0), 0, coi)),
         ],
@@ -170,5 +168,7 @@ def wgrad_lb_call(x: jax.Array, dy: jax.Array, wplan, *,
         out_shape=jax.ShapeDtypeStruct(
             (wplan.hk, wplan.wk, ci_pad, co_pad), jnp.float32),
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(xp, dyp)

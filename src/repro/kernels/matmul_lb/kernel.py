@@ -52,9 +52,6 @@ def matmul_lb_call(x: jax.Array, w: jax.Array,
         (m, n, k, bm, bn, bk)
     nm, nn, nk = m // bm, n // bn, k // bk
     out_dtype = out_dtype or x.dtype
-    if not interpret and jax.default_backend() == "cpu":
-        from repro.kernels.pallas_cpu import ensure_compiled_cpu
-        ensure_compiled_cpu()
     return pl.pallas_call(
         functools.partial(_matmul_kernel, nk=nk),
         grid=(nm, nn, nk),

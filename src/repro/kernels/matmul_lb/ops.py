@@ -76,19 +76,6 @@ def matmul_lb(x: jax.Array, w: jax.Array,
                               to="lax", layer=f"matmul {m}x{k}@{k}x{n}",
                               reason="block shape not mosaic-legal")
         return _lax_matmul(x, w)
-    if tgt is not None and not tgt.interpret \
-            and jax.default_backend() == "cpu":
-        from repro.kernels.pallas_cpu import COMPILED_MAX_GRID_STEPS
-        xp, wp = _pad_to(x, (bm, bk)), _pad_to(w, (bk, bn))
-        steps = (xp.shape[0] // bm) * (wp.shape[1] // bn) \
-            * (xp.shape[1] // bk)
-        if steps > COMPILED_MAX_GRID_STEPS:
-            active_tracer().event(
-                "exec.fallback", target=tgt.name, to="lax",
-                layer=f"matmul {m}x{k}@{k}x{n}",
-                reason=f"grid of {steps} steps exceeds the unrolled "
-                       f"CPU lowering budget")
-            return _lax_matmul(x, w)
     xp = _pad_to(x, (bm, bk))
     wp = _pad_to(w, (bk, bn))
     out = matmul_lb_call(xp, wp, blk=blk,

@@ -31,6 +31,7 @@ import time
 
 import jax
 
+from repro.core.compile_cache import enable_compile_cache
 from repro.models.cnn import init_resnet, init_vgg, resnet_graph
 from repro.serve import FaultPlan, ImageServer, ServingLoop, VirtualClock
 
@@ -56,7 +57,7 @@ def main() -> None:
                              "account-only"),
                     help="execution backend: interpret (Pallas "
                          "interpreter, the default), compiled "
-                         "(interpret=False Pallas), lax (XLA "
+                         "(Mosaic kernels; needs a TPU), lax (XLA "
                          "reference), account-only (plan + ledger, "
                          "no compute)")
     ap.add_argument("--account-only", action="store_true",
@@ -82,6 +83,7 @@ def main() -> None:
                          "clock the trace is bit-deterministic per "
                          "seed")
     args = ap.parse_args()
+    enable_compile_cache()
 
     key = jax.random.PRNGKey(args.seed)
     if args.model == "resnet":

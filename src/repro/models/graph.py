@@ -280,7 +280,7 @@ def graph_plan_handles(graph: ConvGraph, h: int, w: int, *, batch: int,
                        in_ch: int = 3, dtype_bytes: int = 4,
                        vmem_budget: int | None = None,
                        training: bool = False, strict: bool = True,
-                       verify: bool = False):
+                       verify: bool = False, target: str = "interpret"):
     """Exported accounting handles for the whole graph at an arrival
     batch: ``[(ConvLayer, ConvPlan)]`` per conv stage, from the same
     memoized ``plan_conv`` cache the kernel path's jit trace resolves
@@ -298,6 +298,9 @@ def graph_plan_handles(graph: ConvGraph, h: int, w: int, *, batch: int,
     ``vmem_budget=None`` yields the kernel's own execution plans; an
     explicit budget (e.g. the paper's 1 MiB GBuf) yields the
     accounting plans the ledger scores distance-to-bound with.
+
+    ``target`` is the legality profile every plan is made for
+    (``"mosaic"`` yields the compiled kernels' own plans).
 
     ``verify=True`` runs the exported handles through the static
     verifier (:func:`repro.analysis.plan_check.audit_handles`) and
@@ -322,7 +325,7 @@ def graph_plan_handles(graph: ConvGraph, h: int, w: int, *, batch: int,
                          pool=st.pool if st.fused_pool else 1,
                          residual=st.residual,
                          dtype_bytes=dtype_bytes,
-                         vmem_budget=vmem_budget)
+                         vmem_budget=vmem_budget, target=target)
         if training:
             entry = (layer, plan_conv_training(
                 plan, batch=batch, groups=node.groups,
@@ -336,7 +339,7 @@ def graph_plan_handles(graph: ConvGraph, h: int, w: int, *, batch: int,
                                                audit_handles)
         audit = audit_handles(handles, batch=batch,
                               dtype_bytes=dtype_bytes,
-                              vmem_budget=vmem_budget)
+                              vmem_budget=vmem_budget, target=target)
         if not audit.ok:
             diags = audit.errors() or [Diagnostic(
                 rule="audit.traffic", severity="error",

@@ -1,72 +1,12 @@
-"""JAX version compatibility for ``shard_map`` (the repo's compat policy).
+"""The repo's one spelling of ``shard_map`` and ``axis_size``.
 
-The pinned toolchain is JAX 0.4.37, where ``shard_map`` lives in
-``jax.experimental.shard_map`` and takes ``check_rep=``.  Newer JAX
-promotes it to ``jax.shard_map`` and renames the flag ``check_vma=``
-(varying-manual-axes check).  Every sharded model used to inline its
-own copy of the import dance *and* hard-coded ``check_vma=False``,
-which raises ``TypeError: unexpected keyword argument`` on 0.4.37 —
-this module is the single place that knows about both spellings.
-
-Use :func:`shard_map` exactly like the real one.  The installed JAX's
-native spelling is always forwarded verbatim; the *other* spelling is
-translated when the installed JAX is newer (``check_vma``-era), and
-dropped when it is older — 0.4.x ``check_rep=False`` rejects
-replicated (``P()``) out_specs, so "don't be strict" there maps to the
-0.4.x default instead.
+Sharded models import both from here (lint rule L001), so an API move
+in a later JAX is one edit, not one per model.
 """
 
 from __future__ import annotations
 
-import inspect
-from typing import Any
+import jax
 
-try:                                      # JAX >= 0.5: public top-level API
-    _shard_map = __import__("jax").shard_map
-except AttributeError:                    # JAX 0.4.x: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# Which replication/VMA-check keyword does this JAX accept (if any)?
-_PARAMS = ()
-try:
-    _PARAMS = tuple(inspect.signature(_shard_map).parameters)
-except (TypeError, ValueError):  # pragma: no cover - exotic wrappers
-    pass
-_CHECK_KW = ("check_vma" if "check_vma" in _PARAMS
-             else "check_rep" if "check_rep" in _PARAMS
-             else None)
-
-
-def shard_map(f, *args: Any, **kwargs: Any):
-    """Version-portable ``shard_map(f, mesh=..., in_specs=..., out_specs=...)``.
-
-    ``check_vma``/``check_rep`` kwargs are normalized to the installed
-    JAX's spelling, or dropped when the installed JAX predates both.
-    """
-    used = {a: kwargs.pop(a) for a in ("check_vma", "check_rep")
-            if a in kwargs}
-    for alias, check in used.items():
-        if alias == _CHECK_KW:
-            # native spelling for this JAX: forward verbatim
-            kwargs[_CHECK_KW] = check
-        elif _CHECK_KW == "check_vma":
-            kwargs[_CHECK_KW] = check       # old-style caller, new JAX
-        # else: check_vma on a check_rep-era JAX — drop it.  On 0.4.x
-        # ``check_rep=False`` *rejects* replicated (``P()``) out_specs
-        # (scalars like losses become _SpecError), so the right
-        # translation of "don't be strict" there is the 0.4.x default,
-        # check_rep=True.
-    return _shard_map(f, *args, **kwargs)
-
-
-def axis_size(axis_name) -> Any:
-    """``jax.lax.axis_size`` shim (the primitive landed after 0.4.37).
-
-    The fallback ``psum(1, axis)`` is constant-folded by JAX to the
-    mesh axis size — no collective is emitted.
-    """
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size

@@ -3,8 +3,9 @@
 The tentpole claim is that gradients no longer merely *plan* through
 the paper dataflow but execute through it: dgrad as the lhs-dilated
 compact-plane walk of the forward kernel (any stride), wgrad through
-the dW-stationary kernel — at both the Pallas interpreter and the
-compiled CPU lowering.  These properties sweep random geometries
+the dW-stationary kernel — under the Pallas interpreter, on both the
+interpret-profile plans and the mosaic plans a TPU run executes.
+These properties sweep random geometries
 (stride, kernel size, padding) and require (a) grads match the lax
 VJP to 1e-4 and (b) zero ``exec.fallback`` tallies, so the match is
 evidence about the kernels, not about a quiet lax escape.  A final
@@ -12,12 +13,14 @@ fetch-count check pins the executing wgrad's ``kernel.wgrad`` traffic
 event to ``WgradPlan.traffic`` word for word.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
 from _hypothesis_compat import given, settings, st
 
-from repro.core.exec_target import COMPILED, INTERPRET, LAX
+from repro.core.exec_target import INTERPRET, LAX
 from repro.kernels.conv_lb.ops import (conv2d_lb, exec_fallback_counts,
                                        plan_conv, plan_conv_wgrad,
                                        reset_fallback_counts)
@@ -26,6 +29,8 @@ from repro.obs import Tracer
 
 MB = 1 << 20
 TOL = 1e-4
+# the interpreter running the plans a compiled (Mosaic) run would run
+MOSAIC_INTERPRET = dataclasses.replace(INTERPRET, plan_target="mosaic")
 
 
 def _grads(x, w, stride, pad, tgt):
@@ -64,17 +69,19 @@ def test_interpret_backward_matches_lax_vjp(h, w, hk, wk, stride,
 @settings(max_examples=4, deadline=None)
 @given(st.sampled_from([8, 12]), st.sampled_from([1, 3]),
        st.sampled_from([1, 2]), st.integers(0, 1))
-def test_compiled_backward_matches_lax_vjp(h, hk, stride, pad_idx):
-    """The same property under ``interpret=False`` on a lane-aligned
-    geometry: the compiled CPU lowering's dgrad + wgrad match lax and
-    nothing degrades to the interpreter or the lax VJP."""
+def test_mosaic_plan_backward_matches_lax_vjp(h, hk, stride, pad_idx):
+    """The same property on a lane-aligned geometry under the mosaic
+    plans a compiled run executes (full-row sublane-padded x tiles,
+    LANE channel blocks, sublane-padded wgrad strips), run by the
+    interpreter: dgrad + wgrad match lax and nothing degrades to the
+    lax VJP.  Mosaic compiles these kernels in test_mosaic_compile."""
     py = min(pad_idx, hk - 1)
     key = jax.random.PRNGKey(h * 29 + hk * 11 + stride * 5 + pad_idx)
     x = jax.random.normal(key, (1, h, h, 128))
     wgt = jax.random.normal(jax.random.fold_in(key, 1),
                             (hk, hk, 128, 128)) * 0.05
     reset_fallback_counts()
-    gx, gw = _grads(x, wgt, stride, (py, py), COMPILED)
+    gx, gw = _grads(x, wgt, stride, (py, py), MOSAIC_INTERPRET)
     assert not exec_fallback_counts(), exec_fallback_counts()
     gx_l, gw_l = _grads(x, wgt, stride, (py, py), LAX)
     assert float(jnp.max(jnp.abs(gx - gx_l))) < TOL

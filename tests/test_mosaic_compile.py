@@ -1,0 +1,92 @@
+"""Mosaic compiles the main path's conv kernels for a v5e chip.
+
+Ahead-of-time compiles (no chip attached: the TPU compiler targets a
+described ``v5e:2x2`` topology) of the forward kernel and of the
+backward's dgrad + wgrad kernels at real widths, on the plans a
+``target="compiled"`` run executes:
+
+  * VGG16/224 conv1_1 (the Ci=3 contraction), conv3_3 (256 channels,
+    fused 2x2 pool) and conv5_1 (512 channels, a 14-wide plane padded
+    to the 16-row sublane);
+  * ResNet-20/32 s2b0_a (3x3 stride 2: strided window loads, and the
+    lhs-dilated dgrad) and s2b0_proj (the 1x1 stride-2 projection).
+
+Each compile must contain the Pallas kernels (``tpu_custom_call``) and
+record no ``exec.fallback``: what interpret mode accepts but Mosaic
+refuses (unaligned tiles, strided value slices, interior padding,
+more VMEM than the kernel asks for) fails here, not on the chip.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core.exec_target import COMPILED
+from repro.kernels.conv_lb.ops import (conv2d_lb, exec_fallback_counts,
+                                       reset_fallback_counts)
+
+# (name, batch, plane, ci, co, kernel, stride, pad, pool)
+LAYERS = {
+    "vgg_conv1_1": (8, 224, 3, 64, 3, 1, 1, 1),
+    "vgg_conv3_3": (8, 56, 256, 256, 3, 1, 1, 2),
+    "vgg_conv5_1": (8, 14, 512, 512, 3, 1, 1, 1),
+    "resnet_s2b0_a": (8, 32, 16, 32, 3, 2, 1, 1),
+    "resnet_s2b0_proj": (8, 32, 16, 32, 1, 2, 0, 1),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache; keep it out of the way while these run
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
+            for s in shapes]
+    reset_fallback_counts()
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert not exec_fallback_counts(), exec_fallback_counts()
+    return hlo.count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("layer", sorted(LAYERS))
+def test_forward_kernel_compiles(one_chip, layer):
+    b, hw, ci, co, k, stride, pad, pool = LAYERS[layer]
+
+    def fwd(x, w, bias):
+        return conv2d_lb(x, w, bias, stride=stride, padding=pad,
+                         relu=True, pool=pool, target=COMPILED)
+
+    assert _compile(fwd, one_chip, (b, hw, hw, ci), (k, k, ci, co),
+                    (co,)) == 1
+
+
+@pytest.mark.parametrize("layer", ["resnet_s2b0_a", "resnet_s2b0_proj",
+                                   "vgg_conv3_3"])
+def test_backward_kernels_compile(one_chip, layer):
+    """dgrad (lhs-dilated for the strided layers) + wgrad: two kernels
+    — the VJP never re-runs the forward kernel."""
+    b, hw, ci, co, k, stride, pad, pool = LAYERS[layer]
+
+    def grads(x, w):
+        return jax.grad(lambda x, w: conv2d_lb(
+            x, w, stride=stride, padding=pad, relu=True, pool=pool,
+            target=COMPILED).sum(), argnums=(0, 1))(x, w)
+
+    assert _compile(grads, one_chip, (b, hw, hw, ci), (k, k, ci, co)) == 2

@@ -55,6 +55,21 @@ def test_resnet_graph_audits_clean_at_paper_budget():
     assert audit.ok, audit.report()
 
 
+@pytest.mark.parametrize("model", ["vgg16_224", "resnet20_32"])
+def test_compiled_path_plans_audit_clean_under_mosaic(model):
+    """The plans a ``target="compiled"`` run executes — fwd, dgrad and
+    wgrad of every node at the scoped VMEM limit, planned under the
+    mosaic profile — are all mosaic-legal (the ResNet 1x1 stride-2
+    projections included) and account exactly."""
+    if model == "vgg16_224":
+        graph, hw = vgg_graph(init_vgg(jax.random.PRNGKey(0))), 224
+    else:
+        graph, hw = resnet_graph(), 32
+    audit = pc.audit_graph(graph, hw, hw, batch=8, training=True,
+                           target=pc.TARGET_MOSAIC)
+    assert audit.ok and audit.legal_frac == 1.0, audit.report()
+
+
 def test_audit_forward_only_handles():
     audit = pc.audit_graph(resnet_graph(), 32, 32, batch=8,
                            vmem_budget=MB, training=False)
