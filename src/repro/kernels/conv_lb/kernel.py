@@ -203,7 +203,8 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
                  b_block: int = 1,
                  y_block: int, x_block: int,
                  ci_block: int, co_block: int,
-                 out_dtype=None, interpret: bool = True) -> jax.Array:
+                 out_dtype=None, interpret: bool = True,
+                 name: str = "conv_fwd") -> jax.Array:
     """x: (B, Hp, Wp, Ci) pre-padded NHWC; w: (Hk, Wk, Ci, Co);
     bias: (1, Co) or None; residual: (B, Ho, Wo, Co) pre-pool tensor
     added on the psum tile before the ReLU (the residual join of a
@@ -214,6 +215,10 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
     materialized); ``pad`` is the conv padding of the logical dilated
     plane and ``out_plane`` the padded (Ho, Wo) — both required because
     neither is derivable from the compact shape alone.
+
+    ``name`` names the kernel on the device (the HLO instruction a
+    profile shows): ``conv_fwd``, or ``conv_dgrad`` for a backward's
+    data-gradient conv.
 
     See the module docstring for the padding/divisibility contract."""
     b, hp, wp, ci = x.shape
@@ -313,4 +318,5 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name=name,
     )(*operands)

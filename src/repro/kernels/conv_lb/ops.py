@@ -1062,7 +1062,7 @@ def _pad_axis(a, axis, target):
 
 def _conv_one_group(x, w, bias, residual, plan: ConvPlan, py: int,
                     px: int, relu: bool, out_dtype,
-                    interpret: bool) -> jax.Array:
+                    interpret: bool, name: str) -> jax.Array:
     from repro.kernels.conv_lb.kernel import conv_lb_call
 
     b = x.shape[0]
@@ -1106,7 +1106,8 @@ def _conv_one_group(x, w, bias, residual, plan: ConvPlan, py: int,
                                   if plan.lhs_dilated else None),
                        b_block=blk.b, y_block=blk.y, x_block=blk.x,
                        ci_block=blk.ci, co_block=blk.co,
-                       out_dtype=out_dtype, interpret=interpret)
+                       out_dtype=out_dtype, interpret=interpret,
+                       name=name)
     return out[:b, :plan.ho // plan.pool, :plan.wo // plan.pool, :co]
 
 
@@ -1171,7 +1172,8 @@ def _lax_epilogue(y, bias, relu, pool, residual=None):
                                    "interpret", "fallback", "autotune",
                                    "target",
                                    "b_block", "y_block", "x_block",
-                                   "ci_block", "co_block"))
+                                   "ci_block", "co_block",
+                                   "kernel_name"))
 def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
               residual: jax.Array | None = None,
               *, stride=1, padding=0, dilation=1, lhs_dilation=1,
@@ -1181,7 +1183,8 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
               y_block: int | None = None, x_block: int | None = None,
               ci_block: int | None = None, co_block: int | None = None,
               interpret: bool = True, autotune: bool = True,
-              fallback: bool = False, target=None) -> jax.Array:
+              fallback: bool = False, target=None,
+              kernel_name: str = "conv_fwd") -> jax.Array:
     """NHWC conv through the paper-dataflow batch-folded tiled kernel.
 
     x: (B, H, W, Ci); w: (Hk, Wk, Ci/groups, Co)
@@ -1225,6 +1228,10 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
     the ``lax`` VJP wholesale, loudly (``exec.fallback`` events +
     :func:`exec_fallback_counts`), but remain planned and accounted
     through the same handles.
+
+    ``kernel_name`` names the Pallas call on the device (``conv_fwd``;
+    the backward's own dgrad conv passes ``conv_dgrad``, and the wgrad
+    kernel is ``conv_wgrad``), so a profile tells the passes apart.
     """
     tgt = None if target is None else resolve_target(target)
     if tgt is not None:
@@ -1321,7 +1328,8 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
             rg = (None if residual is None
                   else residual[..., g * co_g:(g + 1) * co_g])
             outs.append(_conv_one_group(xg, wg, bg, rg, plan, py, px,
-                                        relu, x.dtype, interpret))
+                                        relu, x.dtype, interpret,
+                                        kernel_name))
         return outs[0] if groups == 1 else jnp.concatenate(outs, axis=-1)
 
     @jax.custom_vjp
@@ -1397,7 +1405,8 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
                                     (wk - 1) * dx - px),
                            dilation=(dy, dx), lhs_dilation=(sy, sx),
                            interpret=interpret,
-                           autotune=autotune, target=tgt)
+                           autotune=autotune, target=tgt,
+                           kernel_name="conv_dgrad")
             gx = gx[:, :h, :wd]
         else:
             gx = _dgrad_lax_fallback(

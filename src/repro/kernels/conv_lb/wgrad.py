@@ -171,4 +171,5 @@ def wgrad_lb_call(x: jax.Array, dy: jax.Array, wplan, *,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="conv_wgrad",
     )(xp, dyp)

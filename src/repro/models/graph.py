@@ -248,13 +248,16 @@ def graph_forward(graph: ConvGraph, conv_params, x, *,
                       groups=node.groups, relu=node.relu,
                       pool=st.pool if st.fused_pool else 1,
                       target=tgt)
-            if timing:
-                with tr.span("graph.layer", layer=node.name,
-                             model=graph.name):
-                    y = conv2d_lb_timed(src, p["w"], bias, res,
-                                        tracer=tr, **kw)
-            else:
-                y = conv2d_lb(src, p["w"], bias, res, **kw)
+            # the layer's name on its device ops (HLO op_name), in
+            # the forward and in the backward that differentiates it
+            with jax.named_scope(node.name):
+                if timing:
+                    with tr.span("graph.layer", layer=node.name,
+                                 model=graph.name):
+                        y = conv2d_lb_timed(src, p["w"], bias, res,
+                                            tracer=tr, **kw)
+                else:
+                    y = conv2d_lb(src, p["w"], bias, res, **kw)
             if st.pool > 1 and not st.fused_pool:
                 y = jax.lax.reduce_window(
                     y, -jnp.inf, jax.lax.max, (1, st.pool, st.pool, 1),

@@ -29,7 +29,18 @@ Design contract:
     ``tracer=`` and fall back to :func:`active_tracer`; the module
     global behind it is mutated only via :func:`set_active` /
     :meth:`Tracer.activate`, which lint rule L006 confines to this
-    package (callers use the ``with tracer.activate():`` scope).
+    package (callers use the ``with tracer.activate():`` scope);
+  * **on the profiler's clock** — while an enabled tracer's stacked
+    span (:meth:`Tracer.span`) is open it also holds a
+    ``jax.profiler.TraceAnnotation`` of the same name, so a profile
+    captured meanwhile shows the span among the device's operations
+    on one clock (the tracer's own clock is not the profiler's: its
+    records cannot be laid beside a device trace after the fact).
+    ``jax.profiler`` is imported at the first enabled span, never by
+    importing this module or by :data:`NULL_TRACER`.  Detached spans
+    (:meth:`Tracer.begin` / :meth:`Tracer.end`) may end on another
+    thread, which an annotation cannot, and instant events have no
+    extent: both stay in the tracer's memory only.
 """
 
 from __future__ import annotations
@@ -163,25 +174,31 @@ class _SpanCtx:
     ``@tracer.span("plan.search")`` and ``with tracer.span(...)``
     are the same instrumentation idiom."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_span")
+    __slots__ = ("_tracer", "_name", "_attrs", "_span", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
         self._span: Span | None = None
+        self._annotation = None
 
     def __enter__(self) -> Span:
+        import jax.profiler
+
+        self._annotation = jax.profiler.TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._span = self._tracer._open(self._name, dict(self._attrs),
                                         stacked=True)
         return self._span
 
     def __exit__(self, et, ev, tb) -> bool:
-        span = self._span
-        self._span = None
+        span, annotation = self._span, self._annotation
+        self._span = self._annotation = None
         if et is not None:
             span.set(error=repr(ev))
         self._tracer._close(span, stacked=True)
+        annotation.__exit__(et, ev, tb)
         return False
 
     def __call__(self, fn: Callable) -> Callable:
@@ -264,7 +281,8 @@ class Tracer:
 
     def span(self, name: str, **attrs) -> _SpanCtx:
         """A nested span: context manager or decorator.  Parentage
-        follows the per-thread enter/exit stack."""
+        follows the per-thread enter/exit stack; while open, the span
+        is also a ``jax.profiler.TraceAnnotation`` of the same name."""
         if not self.enabled:
             return NULL_SPAN
         return _SpanCtx(self, name, attrs)

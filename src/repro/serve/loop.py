@@ -117,6 +117,10 @@ class TrackedRequest:
     error: str | None = None           # set iff FAILED
     shed_reason: str | None = None     # set iff SHED
     terminal_at: float | None = None
+    # loop clock at the start of its first dispatch attempt (kept
+    # across retries; None while queued, or if shed before dispatch):
+    # arrival -> dispatched_at is the request's queue wait
+    dispatched_at: float | None = None
     # the request's lifecycle span (begun at admission, ended at the
     # terminal transition — possibly on another thread); NULL_SPAN
     # when tracing is off
@@ -327,7 +331,7 @@ class ServingLoop:
         """Admit (or immediately shed) one request; returns its rid.
 
         ``deadline_s`` overrides the loop default for this request."""
-        with self._lock:
+        with self._lock, self.tracer.span("loop.admit"):
             now = self._clock() if now is None else now
             deadline = self.deadline_s if deadline_s is None \
                 else deadline_s
@@ -481,6 +485,9 @@ class ServingLoop:
                 self.counters["peak_inflight"], self._inflight)
             self._refresh_gauges()
             t0 = self._clock()
+            for t in tracked:
+                if t.dispatched_at is None:
+                    t.dispatched_at = t0
         attempt_span = tr.begin(
             "dispatch.attempt", bucket=job.bucket, mode=mode.name,
             attempt=job.attempts + 1,

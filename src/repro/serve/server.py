@@ -177,9 +177,12 @@ class ImageServer:
                 raise ValueError("compute servers need image payloads")
             n = 1 if n_images is None else int(n_images)
         else:
-            images = jnp.asarray(images, self.dtype)
+            # the host-to-device copy, on the submitting thread
+            with self.tracer.span("serve.h2d") as h2d:
+                images = jnp.asarray(images, self.dtype)
             if images.ndim == 3:
                 images = images[None]
+            h2d.set(n_images=int(images.shape[0]))
             if images.shape[1:] != (self.h, self.w, self.in_ch):
                 raise ValueError(f"expected (*, {self.h}, {self.w}, "
                                  f"{self.in_ch}) images, got "
@@ -190,7 +193,6 @@ class ImageServer:
         rid = self.reserve_rid()
         self.queue.submit(ImageRequest(rid=rid, n_images=n, arrival=now,
                                        images=images))
-        self.tracer.event("serve.admit", rid=rid, n_images=n)
         self.metrics.counter("serve_admitted").inc()
         self.metrics.gauge("serve_queue_depth").set(self.queue.depth)
         return rid
@@ -235,8 +237,6 @@ class ImageServer:
             self.metrics.counter("plan_cache_miss").inc()
         else:
             self._counters["plan_hits"] += 1
-            self.tracer.event("plan.cache_hit", bucket=int(bucket),
-                              model=self.graph.name)
             self.metrics.counter("plan_cache_hit").inc()
         return self._handles[key]
 
@@ -287,12 +287,16 @@ class ImageServer:
         tgt = self.target.clamp(target)
         if not tgt.compute:
             return None
-        payload = jnp.concatenate([r.images for r in group], axis=0)
-        pad = bucket - payload.shape[0]
-        if pad:
-            payload = jnp.pad(payload,
-                              ((0, pad), (0, 0), (0, 0), (0, 0)))
         tr = self.tracer
+        n_images = sum(r.n_images for r in group)
+        pad = bucket - n_images
+        # eager device ops, dispatched one by one from the host
+        with tr.span("serve.assemble", bucket=int(bucket),
+                     n_images=n_images):
+            payload = jnp.concatenate([r.images for r in group], axis=0)
+            if pad:
+                payload = jnp.pad(payload,
+                                  ((0, pad), (0, 0), (0, 0), (0, 0)))
         # the dispatch's accounted bytes (same handles the ledger
         # charges) ride on the span next to the measured seconds —
         # one span, both halves of the achieved-GB/s ratio
@@ -303,7 +307,7 @@ class ImageServer:
                 * self.dtype.itemsize
         with tr.span("serve.execute", bucket=int(bucket),
                      mode=tgt.name,
-                     n_images=int(payload.shape[0]) - pad,
+                     n_images=n_images,
                      traffic_bytes=n_bytes) as sp:
             t0 = tr.now()
             out = jax.block_until_ready(
@@ -318,42 +322,42 @@ class ImageServer:
                   now: float) -> list[ServeResult]:
         """Bookkeeping half of a dispatch: stamp completion, charge
         the ledger, publish results into the bounded window."""
-        # virtual clocks (tests) may stand still or even be skewed
-        # backwards mid-flight; a completion never predates the
-        # dispatch call or any member's arrival (latencies stay >= 0)
-        done = max(self._clock(), now, *(r.arrival for r in group))
-        for r in group:
-            r.done = done
-            self.tracer.event("serve.complete", rid=r.rid,
-                              bucket=int(bucket))
-        handles = self.plan_handles(bucket)
-        entries = [(r.rid, r.n_images) for r in group]
-        charges = self.ledger.charge_batch(
-            entries, handles, bucket=bucket,
-            latencies={r.rid: r.latency for r in group},
-            model=self.graph.name)
-        self._counters["dispatches"] += 1
-        results = []
-        off = 0
-        for r, charge in zip(group, charges):
-            sl = None if logits is None else logits[off:off + r.n_images]
-            off += r.n_images
-            res = ServeResult(rid=r.rid, logits=sl, charge=charge,
-                              latency_s=r.latency)
-            self.results[r.rid] = res
-            results.append(res)
-        # evict oldest-first, but never a result this dispatch just
-        # returned: with keep_results smaller than the group, naive
-        # tail-trimming would drop results the caller is being handed
-        current = {r.rid for r in group}
-        for rid in list(self.results):
-            if len(self.results) <= self.keep_results:
-                break
-            if rid in current:
-                continue
-            del self.results[rid]
-            self._counters["results_evicted"] += 1
-        return results
+        with self.tracer.span("serve.complete", bucket=int(bucket)):
+            # virtual clocks (tests) may stand still or even be skewed
+            # backwards mid-flight; a completion never predates the
+            # dispatch call or any member's arrival (latencies >= 0)
+            done = max(self._clock(), now, *(r.arrival for r in group))
+            for r in group:
+                r.done = done
+            handles = self.plan_handles(bucket)
+            entries = [(r.rid, r.n_images) for r in group]
+            charges = self.ledger.charge_batch(
+                entries, handles, bucket=bucket,
+                latencies={r.rid: r.latency for r in group},
+                model=self.graph.name)
+            self._counters["dispatches"] += 1
+            results = []
+            off = 0
+            for r, charge in zip(group, charges):
+                sl = (None if logits is None
+                      else logits[off:off + r.n_images])
+                off += r.n_images
+                res = ServeResult(rid=r.rid, logits=sl, charge=charge,
+                                  latency_s=r.latency)
+                self.results[r.rid] = res
+                results.append(res)
+            # evict oldest-first, but never a result this dispatch just
+            # returned: with keep_results smaller than the group, naive
+            # tail-trimming would drop results the caller is handed
+            current = {r.rid for r in group}
+            for rid in list(self.results):
+                if len(self.results) <= self.keep_results:
+                    break
+                if rid in current:
+                    continue
+                del self.results[rid]
+                self._counters["results_evicted"] += 1
+            return results
 
     def _dispatch(self, group: list[ImageRequest], bucket: int,
                   now: float) -> list[ServeResult]:

@@ -253,3 +253,20 @@ def test_graph_stage_walk_is_single_source_of_truth():
         assert layer.stride == st.node.stride
         assert plan.residual == st.residual
         assert plan.pool == (st.pool if st.fused_pool else 1)
+
+
+def test_each_node_names_its_device_ops():
+    """graph_forward runs every conv under its node's name scope, so
+    the lowered ops of the forward and of its gradient carry it."""
+    params = init_vgg(jax.random.PRNGKey(0), n_classes=4, width_mult=0.05)
+    g = vgg_graph(params)
+    x = jnp.zeros((1, 8, 8, 3))
+
+    def loss(convs, x):
+        return graph_forward(g, convs, x, target="lax").sum()
+
+    text = jax.jit(jax.grad(loss)).lower(params["convs"], x).as_text(
+        debug_info=True)
+    for node in g.nodes:
+        assert f"jvp({node.name})" in text, node.name
+        assert f"transpose(jvp({node.name}))" in text, node.name

@@ -90,3 +90,30 @@ def test_backward_kernels_compile(one_chip, layer):
             target=COMPILED).sum(), argnums=(0, 1))(x, w)
 
     assert _compile(grads, one_chip, (b, hw, hw, ci), (k, k, ci, co)) == 2
+
+
+def test_kernels_carry_their_pass_names(one_chip):
+    """Each Pallas call is named by its pass, so a device profile tells
+    conv_fwd, conv_dgrad and conv_wgrad apart (the HLO instruction and
+    its op_name), while the custom-call target a reader matches stays
+    tpu_custom_call."""
+    b, hw, ci, co, k, stride, pad, pool = LAYERS["vgg_conv3_3"]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((b, hw, hw, ci), (k, k, ci, co))]
+
+    def fwd(x, w):
+        return conv2d_lb(x, w, padding=pad, relu=True, pool=pool,
+                         target=COMPILED)
+
+    def grads(x, w):
+        return jax.grad(lambda x, w: fwd(x, w).sum(),
+                        argnums=(0, 1))(x, w)
+
+    fwd_hlo = jax.jit(fwd).lower(*args).compile().as_text()
+    bwd_hlo = jax.jit(grads).lower(*args).compile().as_text()
+    calls = [line for line in (fwd_hlo + bwd_hlo).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = sorted(line.split(" = ")[0].split()[-1].lstrip("%")
+                   .split(".")[0] for line in calls)
+    assert names == ["conv_dgrad", "conv_fwd", "conv_wgrad"]
+    assert "/conv_fwd/pallas_call" in fwd_hlo

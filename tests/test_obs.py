@@ -157,6 +157,52 @@ def test_null_tracer_is_inert_and_shared():
     assert off.span("x") is NULL_SPAN and off.records == []
 
 
+@pytest.fixture
+def annotations(monkeypatch):
+    """Names of the profiler annotations entered while the test runs."""
+    import jax.profiler
+
+    entered = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            super().__init__(name, **kw)
+            self.counted = name
+
+        def __enter__(self):
+            entered.append(self.counted)
+            return super().__enter__()
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    return entered
+
+
+def test_stacked_spans_hold_a_profiler_annotation(annotations):
+    tr = Tracer()
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+    tr.span("deco")(lambda: None)()
+    tr.event("instant")
+    tr.end(tr.begin("detached"))
+    # stacked spans only: detached spans and events may cross threads
+    assert annotations == ["outer", "inner", "deco"]
+    with pytest.raises(ValueError):
+        with tr.span("failing"):
+            raise ValueError("x")
+    assert annotations[-1] == "failing"
+    assert tr.find(name="failing")[0].finished
+
+
+def test_null_tracer_creates_no_annotation(annotations):
+    with NULL_TRACER.span("x"):
+        pass
+    NULL_TRACER.span("y")(lambda: None)()
+    with Tracer(enabled=False).span("z"):
+        pass
+    assert annotations == []
+
+
 def test_activate_scopes_the_ambient_tracer():
     assert active_tracer() is NULL_TRACER
     tr = Tracer()
@@ -471,6 +517,50 @@ def test_per_bucket_gauges_track_backlog_and_inflight():
     assert all(v == 0 for v in stats["inflight_by_bucket"].values())
     # drained: the gauge line disappears rather than printing zeros
     assert "backlog" not in server.ledger.format_summary()
+
+
+# --------------------------------------------------------------------------
+# serving spans on the profiler's clock
+# --------------------------------------------------------------------------
+
+def _served(tracer):
+    """A tiny computing serve run (lax pipelines) on ``tracer``."""
+    server = ImageServer(_tiny_params(), 8, 8, target="lax",
+                         wait_budget=0.0, tracer=tracer)
+    loop = ServingLoop(server, deadline_s=None)
+    images = jnp.ones((3, 8, 8, 3))
+    loop.submit(images[:1])
+    loop.submit(images)
+    loop.run_sync()
+    return loop
+
+
+def test_untraced_serving_enters_no_annotation(annotations):
+    loop = _served(None)
+    assert loop.counters["done"] == 2
+    assert annotations == []
+
+
+def test_traced_serving_annotates_where_the_work_happens(annotations):
+    tracer = Tracer()
+    loop = _served(tracer)
+    assert loop.counters["done"] == 2
+    for name in ("loop.admit", "serve.h2d", "serve.assemble",
+                 "serve.execute", "serve.complete"):
+        assert name in annotations, name
+        assert all(s.finished for s in tracer.find(name=name))
+    # the copy sits inside the admission's locked section
+    (admit, _), h2d = (tracer.find(name="loop.admit"),
+                       tracer.find(name="serve.h2d"))
+    assert h2d[0].parent == admit.sid
+    assert [s.attrs["n_images"] for s in h2d] == [1, 3]
+    assert all(s.attrs["bucket"] in (1, 4)
+               for s in tracer.find(name="serve.complete"))
+    # the per-request instant events are gone: spans replace them
+    assert not tracer.find(name="serve.admit")
+    assert not [s for s in tracer.find(name="serve.complete")
+                if s.kind == "instant"]
+    assert not tracer.find(name="plan.cache_hit")
 
 
 # --------------------------------------------------------------------------

@@ -25,6 +25,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 from repro.models.cnn import init_vgg, vgg_graph
 from repro.models.graph import graph_logits
+from repro.obs import Tracer
 from repro.serve import (CircuitBreaker, FaultEvent, FaultPlan,
                          ImageServer, InjectedFault, RequestState,
                          ServingLoop, VirtualClock)
@@ -468,3 +469,39 @@ def test_launch_serve_images_fault_loop_smoke(monkeypatch, capsys):
     serve_images.main()
     out = capsys.readouterr().out
     assert "loop:" in out and "health:" in out
+
+
+def test_dispatch_stamp_marks_the_first_attempt():
+    """dispatched_at is the loop clock at a request's first dispatch
+    attempt: a retry keeps it, and a request shed before any dispatch
+    has none."""
+    clock = VirtualClock()
+    tracer = Tracer(clock=clock)
+    loop = ServingLoop(_account_server(clock, tracer=tracer),
+                       deadline_s=0.12,
+                       fault_plan=FaultPlan.failures(0, service_s=0.05),
+                       service_estimate_s=0.05, seed=0)
+    rids = [loop.submit(n_images=8) for _ in range(5)]
+    for rid in rids:
+        assert loop.requests[rid].dispatched_at is None
+    clock.sleep(0.01)
+    loop.run_sync(tick_s=0.01)
+    _assert_reconciled(loop)
+    reqs = [loop.requests[r] for r in rids]
+    shed = [t for t in reqs if t.state is RequestState.SHED]
+    done = [t for t in reqs if t.state is RequestState.DONE]
+    assert shed and done and len(shed) + len(done) == len(reqs)
+    assert all(t.dispatched_at is None for t in shed)
+    for t in done:
+        assert t.arrival <= t.dispatched_at <= t.terminal_at
+        first = min(s.t0 for s in tracer.find(name="dispatch.attempt")
+                    if str(t.rid) in s.attrs["rids"].split(","))
+        assert t.dispatched_at == first
+    (retried,) = [t for t in done if t.attempts == 2]
+    assert loop.counters["retries"] == 1
+    # the retry's own attempt started after the backoff, yet the stamp
+    # still reads the first attempt's start
+    attempts = sorted(s.t0 for s in tracer.find(name="dispatch.attempt")
+                      if str(retried.rid) in s.attrs["rids"].split(","))
+    assert len(attempts) == 2
+    assert retried.dispatched_at == attempts[0] < attempts[1]
