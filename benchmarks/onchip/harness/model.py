@@ -1,5 +1,6 @@
 """Weights from the seed, and the program's graph checked against the
-configuration's layer table."""
+configuration's layer table: with `reference.py` and `work.py`, the
+default parts of a dense-conv classifier (`harness/parts.py`)."""
 
 from __future__ import annotations
 
@@ -49,15 +50,20 @@ def resolve(path: str):
     return getattr(importlib.import_module(mod), name)
 
 
-def program_graph(cfg: dict, params):
+def build_graph(cfg: dict, params):
     """The program's own ConvGraph for this configuration, built by the
-    builder the configuration names, and checked layer by layer against
-    the table (geometry, effective pool, edges) before anything runs."""
-    from repro.models.graph import graph_stages
-
+    builder the configuration names under `program`."""
     prog = cfg["program"]
     build = resolve(prog["graph"])
-    graph = build(params) if prog["graph_args"] == "params" else build()
+    return build(params) if prog["graph_args"] == "params" else build()
+
+
+def check_graph(cfg: dict, graph):
+    """`graph`, checked layer by layer against the configuration's table
+    (geometry, effective pool, edges) before anything runs; raises where
+    it departs."""
+    from repro.models.graph import graph_stages
+
     h, w, c = cfg["image"]
     stages = graph_stages(graph, h, w, c)
     got = [{"name": st.node.name, "ci": st.node.ci, "co": st.node.co,

@@ -11,6 +11,7 @@ from __future__ import annotations
 import dataclasses
 
 from harness import trace, work
+from harness.parts import parts_of
 
 
 @dataclasses.dataclass
@@ -34,7 +35,8 @@ def conv_roofline(ctx: TracedWindow, patterns) -> float | None:
     device_s = trace.op_seconds(ctx.summary, patterns)
     if device_s <= 0 or not ctx.batches:
         return None
-    least = sum(work.roofline_seconds(work.pass_work(ctx.cfg, b, ctx.passes),
+    pass_work = parts_of(ctx.cfg).pass_work
+    least = sum(work.roofline_seconds(pass_work(ctx.cfg, b, ctx.passes),
                                       ctx.peak) for b in ctx.batches)
     return 100.0 * least / device_s
 

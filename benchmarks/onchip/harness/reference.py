@@ -53,7 +53,7 @@ def _matmul(a, b):
     return jnp.dot(a, b, precision=HIGHEST, preferred_element_type=a.dtype)
 
 
-def _rounded(op, operand_dtype):
+def rounded(op, operand_dtype):
     """`op(a, b)` with both operands rounded to `operand_dtype`, and the
     operands of its two backward products rounded too."""
 
@@ -86,8 +86,8 @@ def logits(cfg: dict, params: dict, images, *, dtype=None, operands=None):
     tensors = {"input": images.astype(dtype)}
     prev = "input"
     for layer, p in zip(cfg["layers"], params["convs"]):
-        conv = _rounded(functools.partial(_conv, stride=layer["stride"],
-                                          pad=layer["pad"]), operands)
+        conv = rounded(functools.partial(_conv, stride=layer["stride"],
+                                         pad=layer["pad"]), operands)
         y = conv(tensors[layer.get("src") or prev], p["w"])
         y = y + p["b"]
         if layer.get("residual"):
@@ -102,21 +102,24 @@ def logits(cfg: dict, params: dict, images, *, dtype=None, operands=None):
         tensors[layer["name"]] = y
         prev = layer["name"]
     feats = tensors[prev].mean(axis=(1, 2)).astype(dtype)
-    return _rounded(_matmul, operands)(feats, params["head"]).astype(
+    return rounded(_matmul, operands)(feats, params["head"]).astype(
         jnp.float32)
 
 
-def loss(cfg: dict, params: dict, images, labels, *, dtype=None):
-    """Mean softmax cross-entropy of the reference logits."""
+def loss(cfg: dict, params: dict, images, labels, *, dtype=None,
+         logits=logits):
+    """Mean softmax cross-entropy of the reference logits (of `logits`,
+    a configuration's own reference where it brings one)."""
     z = logits(cfg, params, images, dtype=dtype)
     logp = jax.nn.log_softmax(z)
     return -jnp.take_along_axis(logp, labels[:, None], axis=1).mean()
 
 
 def logits_in_blocks(cfg: dict, params: dict, images, *, block: int,
-                     dtype=None):
-    """Reference logits of host or device images, `block` rows at a
-    time, so that a large batch fits next to nothing else."""
+                     dtype=None, logits=logits):
+    """Reference logits (of `logits`, as for `loss`) of host or device
+    images, `block` rows at a time, so that a large batch fits next to
+    nothing else."""
     fn = jax.jit(functools.partial(logits, cfg, dtype=dtype))
     outs = [fn(params, jnp.asarray(images[i:i + block]))
             for i in range(0, len(images), block)]

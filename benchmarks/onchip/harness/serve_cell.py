@@ -22,8 +22,8 @@ import numpy as np
 
 from harness import model, reference, trace
 from harness import traffic as gen
+from harness.parts import parts_of, program_graph
 from harness.readers import TracedWindow
-from harness.work import forward_flops
 
 DRAIN_S = 60.0          # how long an answer may come after the window
 TICK_S = 0.0005         # how often the driver looks at the clock
@@ -33,10 +33,10 @@ TRACE_S = 2.0           # and lasts this long (at most 1/4 and 1/2 of
 
 
 def control_forward(cfg):
-    """The reference with float8 product operands, in the program's
-    place: one step below the precision the configuration states, that
-    of every product's operands (PERF.md, section 2)."""
-    ctl = functools.partial(reference.logits, cfg,
+    """The configuration's reference with float8 product operands, in
+    the program's place: one step below the precision the configuration
+    states, that of every product's operands (PERF.md, section 2)."""
+    ctl = functools.partial(parts_of(cfg).logits, cfg,
                             operands="float8_e4m3fn")
     return lambda params, images, _target: ctl(params, images)
 
@@ -138,6 +138,7 @@ class ServeWindow:
     def __init__(self, cell, seed: int, *, target, control: bool = False):
         self.cell, self.seed, self.target = cell, seed, target
         self.control = control
+        self.parts = parts_of(cell.cfg)
 
     def start(self) -> None:
         from repro.serve import ImageServer, ServingLoop
@@ -145,8 +146,8 @@ class ServeWindow:
         self.fallbacks0 = model.fallbacks()
         cfg, tr = self.cell.cfg, self.cell.traffic
         t = [time.monotonic()]
-        self.params = model.init_params(cfg, self.seed)
-        graph = model.program_graph(cfg, self.params)
+        self.params = self.parts.init_params(cfg, self.seed)
+        graph = program_graph(cfg, self.params)
         self.pool = gen.image_pool(tr, cfg, self.seed)
         h, w, c = cfg["image"]
         self.server = ImageServer(
@@ -220,7 +221,7 @@ class ServeWindow:
                 summary=drv.capture.summary(), passes=("fwd",),
                 batches=[b for _, b in groups],
                 real_images=sum(groups.values()),
-                flops_per_image=forward_flops(self.cell.cfg))
+                flops_per_image=self.parts.forward_flops(self.cell.cfg))
         return out
 
     def health(self) -> dict:
@@ -245,6 +246,7 @@ class ServeWindow:
         if not len(self.rows):
             return {"logit_gap": math.inf}
         ref = np.asarray(reference.logits_in_blocks(
-            self.cell.cfg, self.params, self.pool, block=32))[self.rows]
+            self.cell.cfg, self.params, self.pool, block=32,
+            logits=self.parts.logits))[self.rows]
         gap = np.abs(self.got - ref).max(axis=1) / np.abs(ref).max(axis=1)
         return {"logit_gap": float(gap.max())}

@@ -20,9 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import model, reference
+from harness import model
+from harness.parts import parts_of, program_graph
 from harness.readers import TracedWindow
-from harness.work import train_flops
 
 IN_FLIGHT = 2           # steps the host may enqueue ahead of the device
 TRACE_S = 2.0           # the traced steps last about this long (at most
@@ -68,12 +68,13 @@ class TrainWindow:
     def __init__(self, cell, seed: int, *, target, control: bool = False):
         self.cell, self.seed, self.target = cell, seed, target
         self.control = control
+        self.parts = parts_of(cell.cfg)
 
     def _loss(self):
         cfg = self.cell.cfg
         if self.control:
-            return lambda p, b: reference.loss(cfg, p, b["images"],
-                                               b["labels"], dtype="bfloat16")
+            return lambda p, b: self.parts.loss(
+                cfg, p, b["images"], b["labels"], dtype="bfloat16")
         loss = model.resolve(cfg["program"]["train_loss"])
         return lambda p, b: loss(p, b, self.target)
 
@@ -81,8 +82,8 @@ class TrainWindow:
         self.fallbacks0 = model.fallbacks()
         cfg, tr = self.cell.cfg, self.cell.traffic
         dtype = jnp.bfloat16 if self.control else jnp.dtype(cfg["dtype"])
-        self.params0 = model.init_params(cfg, self.seed)
-        model.program_graph(cfg, self.params0)
+        self.params0 = self.parts.init_params(cfg, self.seed)
+        program_graph(cfg, self.params0)
         images, labels = self.cell.kind.ring(tr, cfg, self.seed)
         self.host_ring = (images, labels)
         self.ring = jax.device_put((images, labels))
@@ -147,7 +148,7 @@ class TrainWindow:
                 summary=cap.summary(), passes=("fwd", "wgrad", "dgrad"),
                 batches=[tr["batch"]] * traced,
                 real_images=tr["batch"] * traced,
-                flops_per_image=train_flops(self.cell.cfg))
+                flops_per_image=self.parts.train_flops(self.cell.cfg))
         return out
 
     def health(self) -> dict:
@@ -163,7 +164,7 @@ class TrainWindow:
         images, labels = self.host_ring
         batch = tr["batch"]
         grad_fn = jax.jit(jax.value_and_grad(
-            functools.partial(reference.loss, cfg), argnums=0))
+            functools.partial(self.parts.loss, cfg), argnums=0))
         params = self.params0
         mom = jax.tree_util.tree_map(jnp.zeros_like, params)
         losses = []

@@ -4,8 +4,12 @@ table of its configuration file alone.
 Every roofline and `mfu` number of the benchmark divides by these, so
 a change to the program's planner, tiling or fusion is judged against
 the same work.  A layer row has `ci`, `co`, `k`, `stride`, `pad`, the
-input plane `h`, `w`, the effective `pool` after it and an optional
-`residual` edge.  Word size is that of the configuration's `dtype`.
+input plane `h`, `w`, the effective `pool` after it, an optional
+`residual` edge and optional `groups` (1 where absent: each output
+channel reduces over `ci / groups` input channels).  Word size is that
+of the configuration's `dtype`.  These are the default work counts; a
+configuration with passes other than convolutions counts its own
+(`harness/parts.py`).
 """
 
 from __future__ import annotations
@@ -20,10 +24,16 @@ def out_plane(layer: dict) -> tuple[int, int]:
             (layer["w"] + 2 * p - k) // s + 1)
 
 
+def kernel_words(layer: dict) -> int:
+    """Words of one conv layer's kernel, (k, k, ci / groups, co)."""
+    return layer["k"] ** 2 * (layer["ci"] // layer.get("groups", 1)) \
+        * layer["co"]
+
+
 def conv_macs(layer: dict) -> int:
     """Multiply-accumulates of one image through one conv layer."""
     ho, wo = out_plane(layer)
-    return ho * wo * layer["k"] ** 2 * layer["ci"] * layer["co"]
+    return ho * wo * kernel_words(layer)
 
 
 def head_macs(cfg: dict) -> int:
@@ -49,7 +59,7 @@ def _words(layer: dict, batch: int) -> dict[str, int]:
     ho, wo = out_plane(layer)
     pool = layer.get("pool", 1)
     return {"x": batch * layer["h"] * layer["w"] * layer["ci"],
-            "w": layer["k"] ** 2 * layer["ci"] * layer["co"],
+            "w": kernel_words(layer),
             "b": layer["co"],
             "y": batch * ho * wo * layer["co"],
             "y_pooled": batch * (ho // pool) * (wo // pool) * layer["co"]}

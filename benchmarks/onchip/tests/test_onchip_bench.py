@@ -13,7 +13,7 @@ import jax
 import pytest
 
 import onchip_tiny
-from harness import model, spec
+from harness import parts, spec
 
 BENCH = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -92,8 +92,8 @@ def test_configs_files_and_pairs():
 @pytest.mark.parametrize("name", ["vgg16_224", "resnet20_32"])
 def test_program_graph_matches_the_layer_table(name):
     cfg = json.loads((spec.HERE / "configs" / f"{name}.json").read_text())
-    params = jax.eval_shape(lambda: model.init_params(cfg, 0))
-    graph = model.program_graph(cfg, params)
+    params = jax.eval_shape(lambda: parts.parts_of(cfg).init_params(cfg, 0))
+    graph = parts.program_graph(cfg, params)
     assert len(graph.nodes) == len(cfg["layers"])
 
 
@@ -101,7 +101,7 @@ def test_a_changed_layer_table_is_refused():
     cfg = json.loads((spec.HERE / "configs" / "resnet20_32.json").read_text())
     cfg["layers"][3]["stride"] = 2
     with pytest.raises(ValueError, match="row 3"):
-        model.program_graph(cfg, None)
+        parts.program_graph(cfg, None)
 
 
 def test_without_a_tpu_the_harness_exits_nonzero_and_prints_nothing():
