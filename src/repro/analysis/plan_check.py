@@ -84,6 +84,15 @@ RULES = {
     "conv.lhsdil": "an lhs-dilated plan's compact fetches must start "
                    "on the dilation phase (block*stride divisible by "
                    "lhs_dilation) and fuse no pool/residual epilogue",
+    "conv.norm": "a fused LayerNorm needs the Co block to span every "
+                 "output channel (no Co padding)",
+    "dw.block": "a depthwise channel block must divide the channels "
+                "and, under Mosaic, be a LANE multiple or all of them",
+    "dw.norm": "a depthwise LayerNorm is fused only where the channel "
+               "block spans every channel, and runs as a pass of its "
+               "own otherwise",
+    "dw.vmem": "a depthwise plane's double-buffered input/output/weight "
+               "blocks and its padded scratch must fit the VMEM budget",
     "wgrad.vmem": "resident f32 dW block + double-buffered x/dy "
                   "strips must fit the VMEM budget",
     "wgrad.grid": "dW channel blocks must not exceed the layer's "
@@ -299,6 +308,13 @@ def check_conv_plan(plan, *, batch: int = 1, dtype_bytes: int = 4,
                 f"not divisible by the fused pool {plan.pool}",
                 where=where))
 
+    # -- structural: a fused norm sees every channel ----------------------
+    if getattr(plan, "norm", False) and (blk.co < plan.co
+                                         or plan.co_pad != plan.co):
+        diags.append(_err(
+            "conv.norm", f"a fused LayerNorm over {plan.co} channels "
+            f"sits on a {blk.co}-channel block", where=where))
+
     # -- structural: VMEM fit (double-buffered, epilogue-aware) -----------
     pinned = blk.ci >= plan.ci_pad and blk.co >= plan.co_pad
     need = blk.vmem_bytes(plan.hk, plan.wk, dtype_bytes,
@@ -361,6 +377,48 @@ def check_conv_plan(plan, *, batch: int = 1, dtype_bytes: int = 4,
             message=f"reduction slice ci_block={blk.ci} underfills "
                     f"the {MXU_DIM}-wide MXU",
             hint="grow ci_block toward 128 when VMEM allows"))
+    return diags
+
+
+# --------------------------------------------------------------------------
+# legality pass: DepthwisePlan (the channel-blocked conv_dw kernel)
+# --------------------------------------------------------------------------
+
+def check_dw_plan(plan, *, dtype_bytes: int = 4,
+                  vmem_budget: int | None = None,
+                  target: str = TARGET_INTERPRET,
+                  where: str = "") -> list[Diagnostic]:
+    """Verify one :class:`~repro.kernels.conv_lb.depthwise.DepthwisePlan`:
+    the channel block divides the channels (and is Mosaic-tiled), the
+    norm is fused exactly where the block spans every channel, and the
+    working set fits."""
+    budget = VMEM_LIMIT_BYTES if vmem_budget is None else vmem_budget
+    cb, c = plan.c_block, plan.c
+    diags: list[Diagnostic] = []
+    if cb < 1 or c % cb:
+        diags.append(_err("dw.block", f"channel block {cb} does not "
+                          f"divide {c} channels", where=where))
+    d = _lane_rule(cb, c, "depthwise channel block", target, where)
+    if d:
+        diags.append(d)
+    if plan.norm and (cb != c or plan.norm_pass):
+        diags.append(_err("dw.norm", f"a fused LayerNorm over {c} "
+                          f"channels sits on a {cb}-channel block",
+                          where=where))
+    need = plan.vmem_bytes(dtype_bytes)
+    if need > budget:
+        diags.append(_err("dw.vmem", f"working set {need} B exceeds the "
+                          f"{budget} B budget", where=where))
+    if target == TARGET_MOSAIC:
+        vmem, tile = plan.mosaic_working_set(dtype_bytes)
+        if vmem > budget:
+            diags.append(_err(
+                "mosaic.vmem", f"compiled working set {vmem} B exceeds "
+                f"the {budget} B scoped VMEM limit", where=where))
+        if tile > MOSAIC_TILE_BYTES:
+            diags.append(_err(
+                "mosaic.tile", f"in-kernel row of {tile} B exceeds "
+                f"{MOSAIC_TILE_BYTES} B", where=where))
     return diags
 
 
@@ -580,6 +638,34 @@ def symbolic_wgrad_traffic(wplan, batch: int) -> Traffic:
                    reads_out=0.0, writes_out=float(writes))
 
 
+def symbolic_dw_traffic(plan, batch: int) -> Traffic:
+    """Independent re-derivation of :meth:`DepthwisePlan.traffic`, off
+    the kernel's grid ``(channel-blocks, images)``: the input block's
+    index map ``(bi, 0, 0, ci)`` changes every step, the weight block's
+    ``(0, ci)`` once per channel block, and each output block is
+    written once; an unfused norm pass reads and writes the output
+    again."""
+    nc = plan.c // plan.c_block
+    plane_in = plan.h * plan.w * plan.c_block
+    plane_out = ((plan.h + 2 * plan.py - plan.hk + 1)
+                 * (plan.w + 2 * plan.px - plan.wk + 1) * plan.c_block)
+    again = 1 if plan.norm_pass else 0
+    return Traffic(
+        reads_in=float(nc * batch * (plane_in + again * plane_out)),
+        reads_w=float(nc * plan.hk * plan.wk * plan.c_block),
+        reads_out=0.0,
+        writes_out=float(nc * batch * plane_out * (1 + again)))
+
+
+def symbolic_dw_bound(layer) -> float:
+    """Independent re-derivation of the depthwise bound: each touched
+    input word, each of the k*k*C weights and each output, once."""
+    touched = (layer.batch * layer.ci
+               * layer.fetched_area(layer.wo, layer.ho))
+    return float(touched + layer.hk * layer.wk * layer.co
+                 + layer.batch * layer.co * layer.ho * layer.wo)
+
+
 def symbolic_bound_words(plan, layer) -> float:
     """Independent re-derivation of :meth:`ConvPlan.bound_words`:
     Eq. (15) at the plan's realized footprint, floored at the
@@ -698,6 +784,20 @@ def _audit_conv(name, layer, plan, *, batch, dtype_bytes, vmem_budget,
                           words=acct.total, bound=bound)
 
 
+def _audit_dw(name, layer, plan, *, batch, dtype_bytes, vmem_budget,
+              target) -> PlanAuditEntry:
+    diags = check_dw_plan(plan, dtype_bytes=dtype_bytes,
+                          vmem_budget=vmem_budget, target=target,
+                          where=name)
+    acct = plan.traffic(batch)
+    bound = plan.bound_words(layer)
+    return PlanAuditEntry(
+        name=name, diagnostics=tuple(diags),
+        traffic_ok=_traffic_eq(symbolic_dw_traffic(plan, batch), acct),
+        bound_ok=symbolic_dw_bound(layer) == bound,
+        words=acct.total, bound=bound)
+
+
 def _audit_wgrad(name, wplan, *, batch, dtype_bytes, vmem_budget,
                  target) -> PlanAuditEntry:
     diags = check_wgrad_plan(wplan, dtype_bytes=dtype_bytes,
@@ -719,7 +819,12 @@ def audit_handles(handles, *, batch: int, dtype_bytes: int = 4,
     bound cross-audit against the accountant."""
     entries: list[PlanAuditEntry] = []
     for layer, handle in handles:
-        if hasattr(handle, "fwd"):        # ConvTrainingPlan triple
+        if hasattr(handle, "c_block"):    # DepthwisePlan (fwd only)
+            entries.append(_audit_dw(
+                f"{layer.name}/fwd", layer, handle, batch=batch,
+                dtype_bytes=dtype_bytes, vmem_budget=vmem_budget,
+                target=target))
+        elif hasattr(handle, "fwd"):      # ConvTrainingPlan triple
             entries.append(_audit_conv(
                 f"{layer.name}/fwd", layer, handle.fwd, batch=batch,
                 dtype_bytes=dtype_bytes, vmem_budget=vmem_budget,

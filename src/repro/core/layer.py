@@ -30,6 +30,7 @@ class ConvLayer:
     wk: int             # kernel cols
     stride: int = 1     # D
     pad: int = 0
+    groups: int = 1     # each output channel reduces over ci / groups
 
     # ---- derived dimensions -------------------------------------------------
     @property
@@ -52,7 +53,7 @@ class ConvLayer:
 
     @property
     def n_weights(self) -> int:
-        return self.co * self.ci * self.hk * self.wk
+        return self.co * (self.ci // self.groups) * self.hk * self.wk
 
     @property
     def n_outputs(self) -> int:
@@ -60,8 +61,8 @@ class ConvLayer:
 
     @property
     def macs(self) -> int:
-        """Total multiply-accumulates = B*Wo*Ho*Co*Wk*Hk*Ci."""
-        return self.n_outputs * self.ci * self.hk * self.wk
+        """Total multiply-accumulates = B*Wo*Ho*Co*Wk*Hk*Ci/groups."""
+        return self.n_outputs * (self.ci // self.groups) * self.hk * self.wk
 
     # ---- converted matmul view (paper Fig. 3) -------------------------------
     @property

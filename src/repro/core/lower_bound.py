@@ -162,6 +162,19 @@ def q_dram_graph_serving(stages, *, requests: int) -> float:
                for layer, s in stages)
 
 
+def q_dram_depthwise(layer: ConvLayer) -> float:
+    """Bound of a depthwise layer (``groups == ci == co``).
+
+    Output channel c reads input channel c alone, so no on-chip word
+    serves two channels: the layer is C independent single-plane convs
+    (Ci = Co = 1), whose Eq. (15) read term 2*#MACs/sqrt(R*S) =
+    2*Ho*Wo*sqrt(Hk*Wk/S) per channel falls below one read of the
+    plane once S holds a few kernel windows.  The bound is then the
+    once-per-word floor: every touched input, the Hk*Wk*C weights and
+    every output, each once."""
+    return q_dram_ideal(layer)
+
+
 def q_dram_naive(layer: ConvLayer) -> float:
     """No-reuse implementation: 2 accesses per MAC (Sec. III-B)."""
     return 2.0 * layer.macs

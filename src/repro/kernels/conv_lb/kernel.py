@@ -30,10 +30,12 @@ the convolution-to-MM conversion of paper Fig. 3.  Stride and dilation
 are folded into the in-VMEM strided slice, so WndR survives both.
 
 Fused epilogue (applied inside the flush step, while the psum tile is
-still in VMEM): optional ``bias`` add, ``relu``, and an aligned
-``pool`` x ``pool`` max-pool (stride = pool, VALID: a leading-dim
-split for the rows, strided loads through a lane-wide scratch for the
-columns).  This collapses a
+still in VMEM), in this order: optional ``bias`` add, exact (erf)
+``gelu``, a per-channel layer ``scale``, the ``residual`` join,
+``relu``, a LayerNorm over the channels (``norm``; the Co block spans
+every channel), and an aligned ``pool`` x ``pool`` max-pool (stride =
+pool, VALID: a leading-dim split for the rows, strided loads through
+a lane-wide scratch for the columns).  This collapses a
 CNN layer's ``conv-write -> read -> bias/relu/pool -> write`` HBM round
 trip into the single mandatory output write — with pooling the write
 volume itself drops by pool**2.
@@ -118,17 +120,80 @@ def compact_axis_dims(block: int, halo: int, stride: int, ld: int,
     return compact_halo(halo, ld, pad), (block * stride) // ld, off
 
 
+# LayerNorm's epsilon (ConvNeXt's, for every norm of the network)
+NORM_EPS = 1e-6
+
+# erf(x) = x * P(x^2) / Q(x^2) on [-4, 4], float32 erf's rational
+# approximation (Eigen's, which XLA's float32 erf also uses); erf is
+# +-1 to float32 rounding outside.  Mosaic has no lowering rule for
+# lax.erf, so a kernel epilogue builds it from these primitives.
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08,
+          -2.10102402082508e-06, -5.69250639462346e-05,
+          -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
+
+
+def erf(x):
+    """float32 erf from multiplies, adds and a divide."""
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p = functools.reduce(lambda acc, c: acc * x2 + c, _ERF_P[1:],
+                         jnp.full_like(x2, _ERF_P[0]))
+    q = functools.reduce(lambda acc, c: acc * x2 + c, _ERF_Q[1:],
+                         jnp.full_like(x2, _ERF_Q[0]))
+    return x * p / q
+
+
+def gelu(x):
+    """Exact GELU, x * Phi(x), through :func:`erf`."""
+    return 0.5 * x * (1.0 + erf(x * 0.7071067811865476))
+
+
+def layer_norm(x, w, b):
+    """LayerNorm over the last (channel) axis, biased variance."""
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    d = x - mean
+    var = jnp.mean(d * d, axis=-1, keepdims=True)
+    return d * jax.lax.rsqrt(var + NORM_EPS) * w + b
+
+
+def epilogue(acc, *, bias=None, gelu_act=False, scale=None,
+             residual=None, relu=False, norm=None):
+    """The fused epilogue on an f32 value whose last axis is channels:
+    bias, GELU, layer scale, residual join, ReLU, LayerNorm (``norm``
+    is its ``(w, b)`` rows).  Each absent step adds no operation."""
+    if bias is not None:
+        acc = acc + bias
+    if gelu_act:
+        acc = gelu(acc)
+    if scale is not None:
+        acc = acc * scale
+    if residual is not None:
+        acc = acc + residual.astype(jnp.float32)
+    if relu:
+        acc = jnp.maximum(acc, 0.0)
+    if norm is not None:
+        acc = layer_norm(acc, *norm)
+    return acc
+
+
 def _conv_kernel(*refs, nci: int, hk: int, wk: int,
                  bb: int, ty: int, tx: int,
                  stride: tuple[int, int], dilation: tuple[int, int],
                  lhs_dilation: tuple[int, int],
                  off: tuple[int, int], chalo: tuple[int, int],
                  has_bias: bool, has_residual: bool, relu: bool,
-                 pool: int):
+                 pool: int, gelu_act: bool = False,
+                 has_scale: bool = False, has_norm: bool = False):
     refs = list(refs)
     x_ref, w_ref = refs[:2]
     rest = refs[2:]
     b_ref = rest.pop(0) if has_bias else None
+    s_ref = rest.pop(0) if has_scale else None
+    n_ref = rest.pop(0) if has_norm else None
     r_ref = rest.pop(0) if has_residual else None
     o_ref, acc_ref = rest[:2]
 
@@ -163,13 +228,13 @@ def _conv_kernel(*refs, nci: int, hk: int, wk: int,
 
     @pl.when(pl.program_id(4) == nci - 1)
     def _flush():
-        acc = acc_ref[...]
-        if b_ref is not None:                 # fused epilogue: the psum
-            acc = acc + b_ref[0]              # tile is still in VMEM
-        if r_ref is not None:                 # residual join, pre-ReLU:
-            acc = acc + r_ref[...].astype(jnp.float32)
-        if relu:
-            acc = jnp.maximum(acc, 0.0)
+        # fused epilogue: the psum tile is still in VMEM; the residual
+        # join lands before the ReLU, the norm spans the whole Co block
+        acc = epilogue(
+            acc_ref[...], bias=None if b_ref is None else b_ref[0],
+            gelu_act=gelu_act, scale=None if s_ref is None else s_ref[0],
+            residual=None if r_ref is None else r_ref[...], relu=relu,
+            norm=None if n_ref is None else (n_ref[0], n_ref[1]))
         if pool > 1:
             # rows: max over a leading-dim split of the finished tile
             acc = acc.reshape(bb, ty // pool, pool, tx, cob)
@@ -195,6 +260,9 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
                  bias: jax.Array | None = None,
                  residual: jax.Array | None = None,
                  relu: bool = False, pool: int = 1,
+                 gelu_act: bool = False,
+                 scale: jax.Array | None = None,
+                 norm: jax.Array | None = None,
                  stride: tuple[int, int] = (1, 1),
                  dilation: tuple[int, int] = (1, 1),
                  lhs_dilation: tuple[int, int] = (1, 1),
@@ -209,7 +277,10 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
     bias: (1, Co) or None; residual: (B, Ho, Wo, Co) pre-pool tensor
     added on the psum tile before the ReLU (the residual join of a
     BasicBlock, served by one streamed read per output tile instead of
-    a separate HBM round trip) or None.
+    a separate HBM round trip) or None.  ``gelu_act``, ``scale`` (a
+    (1, Co) row) and ``norm`` (the (2, Co) LayerNorm weight and bias
+    rows; the Co block must be the whole of Co) extend the epilogue,
+    in :func:`epilogue`'s order.
 
     With ``lhs_dilation != (1, 1)`` x is the *compact* plane (zeros not
     materialized); ``pad`` is the conv padding of the logical dilated
@@ -267,7 +338,11 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
                              off=(offy, offx), chalo=(chalo_y, chalo_x),
                              has_bias=bias is not None,
                              has_residual=residual is not None,
-                             relu=relu, pool=pool)
+                             relu=relu, pool=pool, gelu_act=gelu_act,
+                             has_scale=scale is not None,
+                             has_norm=norm is not None)
+    if norm is not None:
+        assert co_block == co, "a fused norm needs every channel"
     in_specs = [
         # overlapping halo tile: element offsets, not block indices
         # (Mosaic takes Element on every dim or none) — an
@@ -289,6 +364,12 @@ def conv_lb_call(x: jax.Array, w: jax.Array, *,
         in_specs.append(pl.BlockSpec(
             (1, co_block), lambda bi, yi, xi, coi, cii: (0, coi)))
         operands.append(bias)
+    for rows in (scale, norm):
+        if rows is not None:
+            in_specs.append(pl.BlockSpec(
+                (rows.shape[0], co_block),
+                lambda bi, yi, xi, coi, cii: (0, coi)))
+            operands.append(rows)
     if residual is not None:
         # pre-pool psum-tile geometry: one streamed fetch per
         # (bi, yi, xi, coi) — the Ci sweep never re-reads it

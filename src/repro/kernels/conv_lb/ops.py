@@ -89,11 +89,13 @@ def _pair(v) -> tuple[int, int]:
 def mosaic_working_set(blk: ConvBlockShape, hk: int, wk: int,
                        dtype_bytes: int, *, stride, dilation,
                        lhs_dilation=(1, 1), pad=(0, 0), pool: int = 1,
-                       residual: bool = False) -> tuple[int, int]:
+                       residual: bool = False,
+                       norm: bool = False) -> tuple[int, int]:
     """``(vmem_bytes, tile_bytes)`` of the compiled kernel for ``blk``:
     :meth:`ConvBlockShape.mosaic_vmem_bytes` at the fetch the BlockSpec
     really makes (the compact halo of an lhs-dilated walk, plus its
-    reconstructed dilated scratch) and the largest in-kernel value."""
+    reconstructed dilated scratch) and the largest in-kernel value; a
+    fused norm holds two more psum-tile temporaries."""
     from repro.kernels.conv_lb.kernel import compact_halo
 
     fetch, rec = [], []
@@ -107,6 +109,8 @@ def mosaic_working_set(blk: ConvBlockShape, hk: int, wk: int,
     vmem = blk.mosaic_vmem_bytes(hk, wk, dtype_bytes, fetch=tuple(fetch),
                                  pool=pool, residual=residual,
                                  dilated=dilated)
+    if norm:
+        vmem += 2 * blk.psum_bytes
     return vmem, blk.mosaic_tile_bytes(dtype_bytes)
 
 
@@ -159,6 +163,9 @@ class ConvPlan:
     # auto-chosen, verified) for — "interpret" or "mosaic"; an
     # ExecTarget.COMPILED execution requires a mosaic-target plan
     target: str = "interpret"
+    # a LayerNorm over the output channels is fused in the epilogue:
+    # the Co block spans every channel (no extra HBM words)
+    norm: bool = False
 
     @property
     def grid(self) -> tuple[int, int, int, int]:
@@ -226,7 +233,7 @@ class ConvPlan:
             self.blocks, self.hk, self.wk, dtype_bytes,
             stride=self.stride, dilation=self.dilation,
             lhs_dilation=self.lhs_dilation, pad=(self.py, self.px),
-            pool=self.pool, residual=self.residual)
+            pool=self.pool, residual=self.residual, norm=self.norm)
 
     def bound_words(self, layer) -> float:
         """This layer's Eq. (15) bound at the realized plan footprint,
@@ -382,6 +389,7 @@ def autotune_conv_blocks(batch: int, ho: int, wo: int, ci: int, co: int,
                          lhs_dilation: tuple[int, int] = (1, 1),
                          pad: tuple[int, int] = (0, 0),
                          pool: int = 1, residual: bool = False,
+                         norm: bool = False,
                          dtype_bytes: int = 4,
                          vmem_budget: int,
                          seed: ConvBlockShape,
@@ -401,6 +409,8 @@ def autotune_conv_blocks(batch: int, ho: int, wo: int, ci: int, co: int,
     ``residual=True`` (a fused join streams an extra double-buffered
     u x co_b operand tile) first shrinks the seed's co_b until the
     join's buffer fits too, so every candidate honors the budget.
+    ``norm=True`` (a fused LayerNorm over the output channels) admits
+    only Co blocks that span every channel.
 
     ``target`` selects the legality profile of
     :mod:`repro.analysis.plan_check`: under ``"interpret"`` (the
@@ -459,7 +469,7 @@ def autotune_conv_blocks(batch: int, ho: int, wo: int, ci: int, co: int,
             vmem, tile = mosaic_working_set(
                 blk, hk, wk, db, stride=stride, dilation=dilation,
                 lhs_dilation=lhs_dilation, pad=pad, pool=pool,
-                residual=residual)
+                residual=residual, norm=norm)
             return vmem <= vmem_budget and tile <= MOSAIC_TILE_BYTES
         pinned = blk.ci >= ci and blk.co >= co
         return blk.vmem_bytes(hk, wk, db, w_pinned=pinned,
@@ -496,7 +506,10 @@ def autotune_conv_blocks(batch: int, ho: int, wo: int, ci: int, co: int,
 
     if mosaic:
         seed = snap_mosaic(seed)
-    while (residual or mosaic) and not fits(seed) and seed.co > 1:
+    if norm:
+        seed = dataclasses.replace(seed, co=co)
+    while (residual or mosaic) and not norm and not fits(seed) \
+            and seed.co > 1:
         shrunk = (balanced_tile(co, seed.co // 2) if not mosaic
                   else max(LANE, (seed.co // 2 // LANE) * LANE)
                   if seed.co > LANE else 0)
@@ -519,7 +532,9 @@ def autotune_conv_blocks(batch: int, ho: int, wo: int, ci: int, co: int,
         x = snap_lhs(x, wo, sx, ldx)
         yp = (y - 1) * sy + (hk - 1) * dy + 1
         xp = (x - 1) * sx + (wk - 1) * dx + 1
-        if mosaic:
+        if norm:
+            cobs = [co]
+        elif mosaic:
             cobs = lane_blocks(co)  # largest first: the first fit wins
         else:
             # largest co_b under the budget: psums 4*b*y*x*co_b plus
@@ -578,7 +593,7 @@ def autotune_conv_blocks(batch: int, ho: int, wo: int, ci: int, co: int,
 def plan_conv(h: int, w: int, ci: int, co: int, hk: int, wk: int, *,
               batch: int = 1, stride=(1, 1), padding=(0, 0),
               dilation=(1, 1), lhs_dilation=(1, 1), pool: int = 1,
-              residual: bool = False,
+              residual: bool = False, norm: bool = False,
               blocks: ConvBlockShape | None = None,
               dtype_bytes: int = 4,
               vmem_budget: int | None = None,
@@ -592,7 +607,9 @@ def plan_conv(h: int, w: int, ci: int, co: int, hk: int, wk: int, *,
     streamed read is accounted in :meth:`ConvPlan.traffic`, its
     double-buffered operand tile in the autotuner's VMEM fit, and its
     resident tile in :meth:`ConvPlan.footprint_elems` (the S the
-    Eq. (15) comparisons are evaluated at).
+    Eq. (15) comparisons are evaluated at).  ``norm=True`` marks a
+    fused LayerNorm over the output channels: the plan's Co block then
+    spans every channel.
 
     ``target`` names the :mod:`repro.analysis.plan_check` legality
     profile the plan must satisfy, and the returned plan *remembers
@@ -642,7 +659,7 @@ def plan_conv(h: int, w: int, ci: int, co: int, hk: int, wk: int, *,
                     batch, ho, wo, ci, co, hk, wk, stride=(sy, sx),
                     dilation=(dy, dx), lhs_dilation=(ldy, ldx),
                     pad=(py, px), pool=pool, residual=residual,
-                    dtype_bytes=dtype_bytes,
+                    norm=norm, dtype_bytes=dtype_bytes,
                     vmem_budget=budget, seed=blocks, target=target)
             _sp.set(blocks=f"b={blocks.b},y={blocks.y},x={blocks.x},"
                            f"ci={blocks.ci},co={blocks.co}")
@@ -675,7 +692,7 @@ def plan_conv(h: int, w: int, ci: int, co: int, hk: int, wk: int, *,
                     lhs_dilation=(ldy, ldx), pool=pool,
                     hk=hk, wk=wk,
                     h=h, w=w, ci=ci, co=co, py=py, px=px,
-                    residual=residual, target=target)
+                    residual=residual, target=target, norm=norm)
     if auto:
         from repro.analysis.plan_check import (PlanLegalityError,
                                                check_conv_plan, errors)
@@ -1062,7 +1079,8 @@ def _pad_axis(a, axis, target):
 
 def _conv_one_group(x, w, bias, residual, plan: ConvPlan, py: int,
                     px: int, relu: bool, out_dtype,
-                    interpret: bool, name: str) -> jax.Array:
+                    interpret: bool, name: str, gelu: bool = False,
+                    scale=None, norm=None) -> jax.Array:
     from repro.kernels.conv_lb.kernel import conv_lb_call
 
     b = x.shape[0]
@@ -1086,10 +1104,10 @@ def _conv_one_group(x, w, bias, residual, plan: ConvPlan, py: int,
               :(nx - 1) * blk.x * plan.stride[1] + blk.halo_x]
     x = _pad_axis(_pad_axis(x, 3, plan.ci_pad), 0, round_up(b, blk.b))
     w = _pad_axis(_pad_axis(w, 2, plan.ci_pad), 3, plan.co_pad)
-    bias2d = None
-    if bias is not None:
-        bias2d = _pad_axis(bias.reshape(1, -1).astype(jnp.float32),
-                           1, plan.co_pad)
+    bias2d, scale2d = (
+        None if v is None else _pad_axis(
+            v.reshape(1, -1).astype(jnp.float32), 1, plan.co_pad)
+        for v in (bias, scale))
     if residual is not None:
         # pad the join operand to the pre-pool psum-tile geometry
         residual = jnp.pad(residual,
@@ -1098,7 +1116,9 @@ def _conv_one_group(x, w, bias, residual, plan: ConvPlan, py: int,
         residual = _pad_axis(_pad_axis(residual, 3, plan.co_pad),
                              0, round_up(b, blk.b))
     out = conv_lb_call(x, w, bias=bias2d, residual=residual, relu=relu,
-                       pool=plan.pool,
+                       pool=plan.pool, gelu_act=gelu, scale=scale2d,
+                       norm=None if norm is None
+                       else norm.astype(jnp.float32),
                        stride=plan.stride, dilation=plan.dilation,
                        lhs_dilation=plan.lhs_dilation,
                        pad=(plan.py, plan.px),
@@ -1148,17 +1168,27 @@ def reset_fallback_counts() -> None:
     FALLBACK_COUNTS.clear()
 
 
-def _lax_epilogue(y, bias, relu, pool, residual=None):
-    """The unfused reference epilogue (bias -> residual join -> relu
-    -> maxpool) — the exact math the kernel fuses on the psum tile."""
+def _lax_epilogue(y, bias, relu, pool, residual=None, *,
+                  gelu: bool = False, scale=None, norm=None):
+    """The unfused reference epilogue (bias -> exact GELU -> layer
+    scale -> residual join -> relu -> LayerNorm over the channels ->
+    maxpool) — the exact math the kernel fuses on the psum tile."""
+    def f32(v):
+        return v.astype(jnp.float32)
+
     if bias is not None:
-        y = (y.astype(jnp.float32) + bias.astype(jnp.float32)
-             ).astype(y.dtype)
+        y = (f32(y) + f32(bias)).astype(y.dtype)
+    if gelu:
+        y = jax.nn.gelu(f32(y), approximate=False).astype(y.dtype)
+    if scale is not None:
+        y = (f32(y) * f32(scale)).astype(y.dtype)
     if residual is not None:
-        y = (y.astype(jnp.float32) + residual.astype(jnp.float32)
-             ).astype(y.dtype)
+        y = (f32(y) + f32(residual)).astype(y.dtype)
     if relu:
         y = jnp.maximum(y, 0).astype(y.dtype)
+    if norm is not None:
+        from repro.kernels.conv_lb.kernel import layer_norm
+        y = layer_norm(f32(y), f32(norm[0]), f32(norm[1])).astype(y.dtype)
     if pool > 1:
         y = jax.lax.reduce_window(y, -jnp.inf, jax.lax.max,
                                   (1, pool, pool, 1), (1, pool, pool, 1),
@@ -1168,7 +1198,7 @@ def _lax_epilogue(y, bias, relu, pool, residual=None):
 
 @partial(jax.jit, static_argnames=("stride", "padding", "dilation",
                                    "lhs_dilation",
-                                   "groups", "relu", "pool",
+                                   "groups", "relu", "pool", "gelu",
                                    "interpret", "fallback", "autotune",
                                    "target",
                                    "b_block", "y_block", "x_block",
@@ -1179,6 +1209,8 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
               *, stride=1, padding=0, dilation=1, lhs_dilation=1,
               groups: int = 1,
               relu: bool = False, pool: int = 1,
+              gelu: bool = False, scale: jax.Array | None = None,
+              norm: jax.Array | None = None,
               b_block: int | None = None,
               y_block: int | None = None, x_block: int | None = None,
               ci_block: int | None = None, co_block: int | None = None,
@@ -1204,7 +1236,17 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
     join costs one streamed read instead of a separate
     write -> read -> add -> write HBM round trip.  ``fallback=True``
     routes through ``lax.conv_general_dilated`` + the unfused epilogue
-    (same math, XLA's schedule).
+    (same math, XLA's schedule).  ``gelu`` (exact, erf), ``scale`` (a
+    (Co,) per-channel layer scale) and ``norm`` (the (2, Co) weight and
+    bias rows of a LayerNorm over the output channels) extend the
+    epilogue: bias -> GELU -> scale -> residual join -> ReLU -> norm ->
+    pool; left unset they add nothing to what the layer traces.
+
+    A depthwise layer (``groups == Ci == Co``, unit stride and
+    dilation, no pool or residual) runs the channel-blocked ``conv_dw``
+    kernel (:mod:`repro.kernels.conv_lb.depthwise`): one
+    ``pallas_call`` for the layer; other grouped layers run one
+    forward kernel per group.
 
     ``target`` (an :class:`~repro.core.exec_target.ExecTarget` or its
     name) is the first-class way to choose the backend and overrides
@@ -1254,23 +1296,57 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
     h_d = (h - 1) * ldy + 1
     wd_d = (wd - 1) * ldx + 1
 
-    def _lax_full(x, w, bias=None, residual=None):
+    epi = (scale, norm)
+    extras = gelu or scale is not None or norm is not None
+
+    def _lax_full(x, w, bias=None, residual=None, epi=(None, None)):
         return _lax_epilogue(_lax_conv(x, w, sy, sx, py, px, dy, dx,
                                        groups, ldy=ldy, ldx=ldx),
-                             bias, relu, pool, residual=residual)
+                             bias, relu, pool, residual=residual,
+                             gelu=gelu, scale=epi[0], norm=epi[1])
 
     if fallback:
-        return _lax_full(x, w, bias, residual)
+        return _lax_full(x, w, bias, residual, epi)
 
     plan_target = tgt.plan_target if tgt is not None else "interpret"
 
-    def _loud_fallback(reason: str) -> jax.Array:
+    def _loud_fallback(reason: str, conv_pass: str = "fwd") -> jax.Array:
         # a request this geometry can't honor degrades to lax with a
         # traced event + counter — never a silent interpreter run
-        record_fallback("fwd", reason,
+        record_fallback(conv_pass, reason,
                         target=tgt.name if tgt is not None else "legacy",
                         layer=f"{ci}->{co}k{hk}x{wk}")
-        return _lax_full(x, w, bias, residual)
+        return _lax_full(x, w, bias, residual, epi)
+
+    depthwise = (groups > 1 and groups == ci == co and pool == 1
+                 and residual is None
+                 and (sy, sx, dy, dx, ldy, ldx) == (1, 1, 1, 1, 1, 1))
+    if norm is not None and groups > 1 and not depthwise:
+        # a per-group kernel sees a slice of the channels it normalizes
+        return _loud_fallback("a LayerNorm over a grouped, not "
+                              "depthwise, conv")
+    if depthwise:
+        from repro.analysis.plan_check import PlanLegalityError
+        from repro.kernels.conv_lb.depthwise import (conv_dw_call,
+                                                     plan_conv_dw)
+        try:
+            dplan = plan_conv_dw(h, wd, ci, hk, wk, padding=(py, px),
+                                 norm=norm is not None,
+                                 dtype_bytes=x.dtype.itemsize,
+                                 target=plan_target)
+        except PlanLegalityError:
+            if plan_target == "interpret":
+                raise
+            return _loud_fallback("no mosaic-legal depthwise plan under "
+                                  "the budget", "dw")
+
+        def _run(x, w, bias, residual, epi):
+            return conv_dw_call(x, w, dplan, bias=bias, scale=epi[0],
+                                norm=epi[1], relu=relu, gelu_act=gelu,
+                                interpret=interpret)
+        return _with_lax_vjp(_run, _lax_full, x, w, bias, residual, epi,
+                             target=tgt, layer=f"{ci}->{co}k{hk}x{wk}",
+                             reason="depthwise forward")
 
     try:
         plan = plan_conv(h_d, wd_d, ci_g, co // groups, hk, wk, batch=b,
@@ -1278,6 +1354,7 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
                          dilation=(dy, dx), lhs_dilation=(ldy, ldx),
                          pool=pool,
                          residual=residual is not None,
+                         norm=norm is not None,
                          dtype_bytes=x.dtype.itemsize,
                          autotune=autotune, target=plan_target)
     except Exception as e:
@@ -1305,7 +1382,7 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
                          dilation=(dy, dx), lhs_dilation=(ldy, ldx),
                          pool=pool,
                          residual=residual is not None, blocks=override,
-                         target=plan_target)
+                         norm=norm is not None, target=plan_target)
         if plan_target != "interpret":
             # explicit overrides bypass plan_conv's gate; a compiled
             # execution still refuses (loudly) to run an illegal shape
@@ -1319,18 +1396,25 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
                     "explicit blocks are not mosaic-legal")
     co_g = co // groups
 
-    def _run(x, w, bias, residual):
-        outs = []
-        for g in range(groups):
-            xg = x[..., g * ci_g:(g + 1) * ci_g]
-            wg = w[..., g * co_g:(g + 1) * co_g]
-            bg = None if bias is None else bias[g * co_g:(g + 1) * co_g]
-            rg = (None if residual is None
-                  else residual[..., g * co_g:(g + 1) * co_g])
-            outs.append(_conv_one_group(xg, wg, bg, rg, plan, py, px,
-                                        relu, x.dtype, interpret,
-                                        kernel_name))
+    def _run(x, w, bias, residual, epi=(None, None)):
+        def cut(v, g):
+            return None if v is None else v[..., g * co_g:(g + 1) * co_g]
+        outs = [_conv_one_group(
+            x[..., g * ci_g:(g + 1) * ci_g], cut(w, g), cut(bias, g),
+            cut(residual, g), plan, py, px, relu, x.dtype, interpret,
+            kernel_name, gelu=gelu, scale=cut(epi[0], g), norm=epi[1])
+            for g in range(groups)]
         return outs[0] if groups == 1 else jnp.concatenate(outs, axis=-1)
+
+    _tgt_name = tgt.name if tgt is not None else "legacy"
+    _layer_tag = f"{ci}->{co}k{hk}x{wk}"
+    if groups != 1 or ldy > 1 or ldx > 1 or extras:
+        # the kernel backward peels only the bias/residual/relu/pool
+        # epilogue of an ungrouped conv
+        return _with_lax_vjp(_run, _lax_full, x, w, bias, residual, epi,
+                             target=tgt, layer=_layer_tag,
+                             reason="grouped, lhs-dilated or "
+                                    "GELU/scale/norm forward")
 
     @jax.custom_vjp
     def kernel_conv(x, w, bias, residual):
@@ -1338,20 +1422,6 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
 
     def _fwd(x, w, bias, residual):
         return kernel_conv(x, w, bias, residual), (x, w, bias, residual)
-
-    _tgt_name = tgt.name if tgt is not None else "legacy"
-    _layer_tag = f"{ci}->{co}k{hk}x{wk}"
-
-    def _bwd_lax_fallback(res, g, reason):
-        # grouped/lhs-dilated forwards: lax VJP wholesale (still
-        # planned and accounted via plan_conv_dgrad/plan_conv_wgrad
-        # handles).  bias/residual=None are leafless pytree primals:
-        # jax.vjp hands back matching None cotangents, so one scaffold
-        # covers every arity
-        record_fallback("bwd", reason, target=_tgt_name,
-                        layer=_layer_tag)
-        _, vjp = jax.vjp(_lax_full, *res)
-        return vjp(g)
 
     def _dgrad_lax_fallback(x, w, gy, reason):
         record_fallback("dgrad", reason, target=_tgt_name,
@@ -1371,9 +1441,6 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
 
     def _bwd(res, g):
         x, w, bias, residual = res
-        if groups != 1 or ldy > 1 or ldx > 1:
-            return _bwd_lax_fallback(
-                res, g, "grouped or lhs-dilated forward")
         # 1) peel the epilogue: recompute the pre-epilogue conv output
         #    (cheaper than spilling it from the fused kernel, whose
         #    whole point is the single post-epilogue write) and pull g
@@ -1430,11 +1497,37 @@ def conv2d_lb(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
     return kernel_conv(x, w, bias, residual)
 
 
+def _with_lax_vjp(run, lax_full, x, w, bias, residual, epi, *, target,
+                  layer: str, reason: str) -> jax.Array:
+    """``run(x, w, bias, residual, epi)`` forward through a kernel whose
+    backward is the lax VJP of ``lax_full`` (same arguments), taken
+    wholesale and loudly: an ``exec.fallback`` event and a "bwd" count
+    when a gradient is asked for.  bias/residual/epi entries of None
+    are leafless pytree primals, so one scaffold covers every arity."""
+    @jax.custom_vjp
+    def conv(x, w, bias, residual, epi):
+        return run(x, w, bias, residual, epi)
+
+    def fwd(*res):
+        return conv(*res), res
+
+    def bwd(res, g):
+        record_fallback("bwd", reason, layer=layer,
+                        target=target.name if target is not None
+                        else "legacy")
+        _, vjp = jax.vjp(lax_full, *res)
+        return vjp(g)
+
+    conv.defvjp(fwd, bwd)
+    return conv(x, w, bias, residual, epi)
+
+
 def conv2d_lb_timed(x: jax.Array, w: jax.Array,
                     bias: jax.Array | None = None,
                     residual: jax.Array | None = None,
                     *, stride=1, padding=0, dilation=1,
                     groups: int = 1, relu: bool = False, pool: int = 1,
+                    gelu: bool = False, scale=None, norm=None,
                     interpret: bool = True, autotune: bool = True,
                     fallback: bool = False, target=None,
                     tracer=None, clock=None,
@@ -1465,23 +1558,38 @@ def conv2d_lb_timed(x: jax.Array, w: jax.Array,
     dy, dx = _pair(dilation)
     b, h, wd, ci = x.shape
     hk, wk, ci_g, co = w.shape
-    plan_kw = dict(batch=b, stride=(sy, sx), padding=(py, px),
-                   dilation=(dy, dx), pool=pool,
-                   residual=residual is not None,
-                   dtype_bytes=x.dtype.itemsize, autotune=autotune)
+    plan_target = tgt.plan_target if tgt is not None else "interpret"
+    if groups > 1 and groups == ci == co and (sy, sx, dy, dx) == (
+            1, 1, 1, 1) and pool == 1 and residual is None:
+        from repro.kernels.conv_lb.depthwise import plan_conv_dw
+
+        def planned(target):
+            return plan_conv_dw(h, wd, ci, hk, wk, padding=(py, px),
+                                norm=norm is not None,
+                                dtype_bytes=x.dtype.itemsize,
+                                target=target)
+        n_plans = 1
+    else:
+        def planned(target):
+            return plan_conv(h, wd, ci_g, co // groups, hk, wk,
+                             batch=b, stride=(sy, sx), padding=(py, px),
+                             dilation=(dy, dx), pool=pool,
+                             residual=residual is not None,
+                             norm=norm is not None and groups == 1,
+                             dtype_bytes=x.dtype.itemsize,
+                             autotune=autotune, target=target)
+        n_plans = groups
     try:
-        plan = plan_conv(h, wd, ci_g, co // groups, hk, wk,
-                         target=tgt.plan_target if tgt is not None
-                         else "interpret", **plan_kw)
+        plan = planned(plan_target)
     except PlanLegalityError:
         # execution will degrade to lax; account the interpret-profile
         # dataflow (the words any planned schedule at least moves)
-        plan = plan_conv(h, wd, ci_g, co // groups, hk, wk, **plan_kw)
+        plan = planned("interpret")
     if tgt is not None:
         mode = tgt.name
     else:
         mode = "lax" if fallback else "kernel"
-    n_bytes = groups * plan.traffic_bytes(b, dtype_bytes=x.dtype.itemsize)
+    n_bytes = n_plans * plan.traffic_bytes(b, dtype_bytes=x.dtype.itemsize)
     with tr.span(name, layer=f"{ci}->{co}k{hk}x{wk}",
                  mode=mode,
                  batch=b, traffic_bytes=n_bytes) as sp:
@@ -1489,6 +1597,7 @@ def conv2d_lb_timed(x: jax.Array, w: jax.Array,
         out = conv2d_lb(x, w, bias, residual, stride=stride,
                         padding=padding, dilation=dilation,
                         groups=groups, relu=relu, pool=pool,
+                        gelu=gelu, scale=scale, norm=norm,
                         interpret=interpret, autotune=autotune,
                         fallback=fallback, target=tgt)
         out = jax.block_until_ready(out)

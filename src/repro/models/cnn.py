@@ -3,9 +3,11 @@
 VGG (the paper's own evaluation workload) is now just a
 :class:`~repro.models.graph.ConvGraph` builder — the ``vgg_*``
 functions are thin compat wrappers over the generic graph walk — and
-ResNet BasicBlock stacks (:func:`resnet_graph`) ride the same IR:
-stride-2 downsampling convs, 1x1 projection shortcuts and residual
-joins all flow through the one planner/forward/accounting surface.
+ResNet BasicBlock stacks (:func:`resnet_graph`) and ConvNeXt
+(:func:`convnext_graph`: depthwise 7x7 convs, LayerNorm, GELU, layer
+scale, a 4x4 patch stem) ride the same IR: stride-2 downsampling
+convs, 1x1 projection shortcuts and residual joins all flow through
+the one planner/forward/accounting surface.
 
 The conv layers run through :mod:`repro.kernels.conv_lb.ops` (the
 spatially-tiled Pallas kernel realizing the paper's dataflow) when
@@ -232,11 +234,76 @@ def resnet_forward(graph: ConvGraph, params, images, target=None):
     return graph_logits(graph, params, images, target=target)
 
 
+# --------------------------------------------------------------------------
+# ConvNeXt — depthwise 7x7 convs, LayerNorm, GELU and layer scale
+# --------------------------------------------------------------------------
+
+def convnext_graph(depths=(3, 3, 9, 3), widths=(96, 192, 384, 768),
+                   in_ch: int = 3, name: str = "convnext_t") -> ConvGraph:
+    """ConvNeXt (Liu et al., arXiv:2201.03545, Sec. 2 and Table 9) as a
+    :class:`ConvGraph`; the defaults build ConvNeXt-T, 58 conv nodes.
+
+    Nodes, in order:
+
+      stem          4x4 stride-4 conv in_ch -> C1, LayerNorm
+      s{i}_down     (stages 2-4) 2x2 stride-2 conv C(i-1) -> Ci
+      s{i}b{j}_dw   7x7 depthwise conv (pad 3), LayerNorm
+      s{i}b{j}_pw1  1x1 conv C -> 4C, GELU
+      s{i}b{j}_pw2  1x1 conv 4C -> C, layer scale, + the block's input
+
+    then mean pool, LayerNorm and a linear head with bias.  The
+    LayerNorm before each downsampling conv is the epilogue of the
+    preceding ``pw2`` (``norm``): that block output has no other
+    consumer, so normalizing it in place is exact.  Every LayerNorm is
+    over the channels with eps 1e-6.  Drop path is the identity at
+    inference and is not represented.
+
+    Parameters (:func:`init_convnext`, or any pytree of this layout):
+    ``{"convs": [...], "head": (C4, classes), "head_b": (classes,),
+    "head_norm_w": (C4,), "head_norm_b": (C4,)}``, one ``convs`` entry
+    per node in the order above, each ``{"w": (k, k, ci / groups, co),
+    "b": (co,)}`` plus ``"scale": (co,)`` on ``pw2`` nodes and
+    ``"norm_w"``, ``"norm_b"``: (co,) on nodes with ``norm``.  A
+    depthwise kernel is (7, 7, 1, C)."""
+    nodes = [ConvNode(name="stem", ci=in_ch, co=widths[0], hk=4, wk=4,
+                      stride=4, pad=0, relu=False, norm=True)]
+    prev = "stem"
+    for si, (depth, c) in enumerate(zip(depths, widths), start=1):
+        if si > 1:
+            nodes.append(ConvNode(name=f"s{si}_down", ci=widths[si - 2],
+                                  co=c, hk=2, wk=2, stride=2, pad=0,
+                                  relu=False))
+            prev = f"s{si}_down"
+        for bi in range(depth):
+            base = f"s{si}b{bi}"
+            last = bi == depth - 1 and si < len(depths)
+            nodes += [
+                ConvNode(name=f"{base}_dw", ci=c, co=c, hk=7, wk=7,
+                         pad=3, groups=c, relu=False, norm=True),
+                ConvNode(name=f"{base}_pw1", ci=c, co=4 * c, hk=1, wk=1,
+                         pad=0, relu=False, gelu=True),
+                ConvNode(name=f"{base}_pw2", ci=4 * c, co=c, hk=1, wk=1,
+                         pad=0, relu=False, scale=True, residual=prev,
+                         norm=last)]
+            prev = f"{base}_pw2"
+    return ConvGraph(name=name, nodes=tuple(nodes), head_norm=True)
+
+
+def init_convnext(key, graph: ConvGraph | None = None,
+                  n_classes: int = 1000, dtype=jnp.float32):
+    """Params of a ConvNeXt graph (default ConvNeXt-T) in
+    :func:`convnext_graph`'s layout: He-init convs, layer scales at the
+    published 1e-6, identity norms, zero biases."""
+    return init_graph(key, graph or convnext_graph(), n_classes=n_classes,
+                      dtype=dtype)
+
+
 __all__ = [
     "ConvStage", "init_vgg", "vgg_layer_dims", "vgg_graph",
     "vgg_conv_geometry", "vgg_conv_layers_for", "vgg_plan_handles",
     "vgg_training_step_report", "vgg_forward", "vgg_loss",
     "resnet_graph", "init_resnet", "resnet_forward",
+    "convnext_graph", "init_convnext",
     # re-exported graph surface (the model-agnostic consumers)
     "ConvGraph", "ConvNode", "graph_forward", "graph_logits",
     "graph_plan_handles", "graph_stages", "graph_training_step_report",

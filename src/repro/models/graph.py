@@ -5,8 +5,8 @@ per-conv-layer, so any conv network's step bound is a *sum over its
 layers* — but only if something model-agnostic can walk the network.
 This module is that walk: a :class:`ConvGraph` of :class:`ConvNode`\\ s,
 each carrying its full conv geometry (kernel extent, stride, padding,
-groups), an epilogue spec (bias/relu/pool), and an optional residual
-input edge, plus one generic geometry resolution
+groups), an epilogue spec (bias, GELU, layer scale, relu, a LayerNorm
+over the channels, pool), and an optional residual input edge, plus one generic geometry resolution
 (:func:`graph_stages`) that every consumer shares:
 
   * :func:`graph_forward` — the executable forward (Pallas kernel or
@@ -31,7 +31,8 @@ Topology: nodes are listed in topological order; each node consumes
 ``src`` (a prior node's name, or :data:`GRAPH_INPUT`; ``None`` chains
 to the immediately preceding node) and may name a ``residual`` tensor
 added to its conv output *before* the ReLU/pool epilogue — exactly
-the BasicBlock join.  The walk validates every edge's plane/channel
+the BasicBlock join, and (after GELU and the layer scale) the ConvNeXt
+block's.  The walk validates every edge's plane/channel
 shapes; a channel mismatch is an error unless ``strict=False``
 (opt-in truncation, the reduced-width smoke-stack compat mode).
 """
@@ -57,7 +58,16 @@ class ConvNode:
     residual join.  ``pool`` is an aligned ``pool x pool`` max-pool
     after the epilogue (fused into the kernel when the output plane
     divides it; skipped entirely when the plane is smaller than the
-    window, matching the VGG walk's small-plane behavior)."""
+    window, matching the VGG walk's small-plane behavior).
+
+    The epilogue runs, in order: bias, exact (erf) ``gelu``, a
+    per-channel layer ``scale`` (weights ``"scale"``), the residual
+    join, ``relu``, a LayerNorm over the output channels (``norm``;
+    weights ``"norm_w"``, ``"norm_b"``, eps 1e-6), the pool.  A node
+    with ``gelu``, ``scale`` and ``norm`` unset traces what it traced
+    before they existed.  ``norm`` on a grouped node needs the
+    depthwise kernel's geometry (``groups == ci == co``, stride 1, no
+    pool or residual), whose channel block sees every channel."""
 
     name: str
     ci: int
@@ -72,6 +82,15 @@ class ConvNode:
     pool: int = 1
     src: str | None = None
     residual: str | None = None
+    gelu: bool = False
+    scale: bool = False
+    norm: bool = False
+
+    @property
+    def depthwise(self) -> bool:
+        """One input channel per output channel: the ``conv_dw``
+        kernel's layer (``groups == ci == co``)."""
+        return self.groups > 1 and self.groups == self.ci == self.co
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,10 +98,15 @@ class ConvGraph:
     """A conv network as a topologically-ordered tuple of nodes.
 
     Hashable (frozen, tuple-of-frozen), so a graph can key plan-handle
-    caches directly.  The graph output is the last node's tensor."""
+    caches directly.  The graph output is the last node's tensor; the
+    classifier mean-pools it and applies the linear ``"head"``.  With
+    ``head_norm`` it is ConvNeXt's classifier: a LayerNorm first
+    (weights ``"head_norm_w"``, ``"head_norm_b"``) and a bias
+    ``"head_b"`` after."""
 
     name: str
     nodes: tuple[ConvNode, ...]
+    head_norm: bool = False
 
     def __post_init__(self):
         seen = {GRAPH_INPUT}
@@ -98,6 +122,12 @@ class ConvGraph:
                 raise ValueError(f"node {node.name!r}: groups="
                                  f"{node.groups} must divide ci={node.ci}"
                                  f" and co={node.co}")
+            if node.norm and node.groups > 1 and not (
+                    node.depthwise and node.stride == 1 and node.pool == 1
+                    and node.residual is None):
+                raise ValueError(f"node {node.name!r}: a LayerNorm over "
+                                 f"a grouped conv needs the depthwise "
+                                 f"kernel's geometry")
             seen.add(node.name)
 
     @property
@@ -173,7 +203,8 @@ def init_graph(key, graph: ConvGraph, n_classes: int = 10,
     pytree shape the VGG stack uses, so one training/serving surface
     covers every graph.  ReLU nodes get the sqrt(2) gain (each ReLU
     halves activation variance); linear nodes (e.g. 1x1 projection
-    shortcuts) stay at plain He."""
+    shortcuts) stay at plain He.  Layer scales start at ConvNeXt's
+    1e-6, norms at the identity (weight 1, bias 0), a head bias at 0."""
     from repro.models.layers import dense_init, split_keys
 
     keys = split_keys(key, len(graph.nodes) + 1)
@@ -186,11 +217,21 @@ def init_graph(key, graph: ConvGraph, n_classes: int = 10,
                              dtype, fan_in=fan_in) * gain}
         if node.bias:
             p["b"] = jnp.zeros((node.co,), dtype)
+        if node.scale:
+            p["scale"] = jnp.full((node.co,), 1e-6, dtype)
+        if node.norm:
+            p["norm_w"] = jnp.ones((node.co,), dtype)
+            p["norm_b"] = jnp.zeros((node.co,), dtype)
         convs.append(p)
     co = graph.out_channels
-    return {"convs": convs,
-            "head": dense_init(keys[-1], (co, n_classes), dtype,
-                               fan_in=co)}
+    params = {"convs": convs,
+              "head": dense_init(keys[-1], (co, n_classes), dtype,
+                                 fan_in=co)}
+    if graph.head_norm:
+        params["head_norm_w"] = jnp.ones((co,), dtype)
+        params["head_norm_b"] = jnp.zeros((co,), dtype)
+        params["head_b"] = jnp.zeros((n_classes,), dtype)
+    return params
 
 
 def graph_forward(graph: ConvGraph, conv_params, x, *,
@@ -248,6 +289,12 @@ def graph_forward(graph: ConvGraph, conv_params, x, *,
                       groups=node.groups, relu=node.relu,
                       pool=st.pool if st.fused_pool else 1,
                       target=tgt)
+            if node.gelu:
+                kw["gelu"] = True
+            if node.scale:
+                kw["scale"] = p["scale"]
+            if node.norm:
+                kw["norm"] = jnp.stack([p["norm_w"], p["norm_b"]])
             # the layer's name on its device ops (HLO op_name), in
             # the forward and in the backward that differentiates it
             with jax.named_scope(node.name):
@@ -271,12 +318,18 @@ def graph_forward(graph: ConvGraph, conv_params, x, *,
 def graph_logits(graph: ConvGraph, params, images, *,
                  target=None, strict: bool = True):
     """Full classification forward: graph features, global mean pool,
-    linear head — ``params`` from :func:`init_graph` (or any pytree of
-    the same ``{"convs", "head"}`` shape).  ``target`` selects the
+    linear head (ConvNeXt's LayerNorm before it and bias after it with
+    ``graph.head_norm``) — ``params`` from :func:`init_graph` (or any
+    pytree of the same shape).  ``target`` selects the
     execution backend exactly as in :func:`graph_forward`."""
     h = graph_forward(graph, params["convs"], images,
                       target=target, strict=strict)
-    return h.mean(axis=(1, 2)) @ params["head"]
+    feats = h.mean(axis=(1, 2))
+    if not graph.head_norm:
+        return feats @ params["head"]
+    from repro.kernels.conv_lb.kernel import layer_norm
+    feats = layer_norm(feats, params["head_norm_w"], params["head_norm_b"])
+    return feats @ params["head"] + params["head_b"]
 
 
 def graph_plan_handles(graph: ConvGraph, h: int, w: int, *, batch: int,
@@ -287,9 +340,12 @@ def graph_plan_handles(graph: ConvGraph, h: int, w: int, *, batch: int,
     """Exported accounting handles for the whole graph at an arrival
     batch: ``[(ConvLayer, ConvPlan)]`` per conv stage, from the same
     memoized ``plan_conv`` cache the kernel path's jit trace resolves
-    against.  Grouped nodes export one per-*group* handle per group
-    (traffic and bound both scale by the group count, exactly as the
-    kernel executes them).  Strided and 1x1 layers flow through the
+    against.  A depthwise node exports one
+    :class:`~repro.kernels.conv_lb.depthwise.DepthwisePlan` handle (its
+    layer carries ``groups``; the same handle in a training export,
+    whose grouped backward runs the lax VJP).  Other grouped nodes
+    export one per-*group* handle per group (traffic and bound both
+    scale by the group count, exactly as the kernel executes them).  Strided and 1x1 layers flow through the
     same planner — nothing above this walk is VGG-shaped.
 
     ``training=True`` exports ``(ConvLayer, ConvTrainingPlan)``
@@ -313,11 +369,24 @@ def graph_plan_handles(graph: ConvGraph, h: int, w: int, *, batch: int,
     set enters its cache.
     """
     from repro.core.layer import ConvLayer
+    from repro.kernels.conv_lb.depthwise import plan_conv_dw
     from repro.kernels.conv_lb.ops import plan_conv, plan_conv_training
 
     handles = []
     for st in graph_stages(graph, h, w, in_ch, strict=strict):
         node = st.node
+        if node.depthwise and node.stride == 1 and st.pool == 1 \
+                and not st.residual:
+            layer = ConvLayer(name=node.name, batch=batch, ci=node.ci,
+                              co=node.co, hi=st.h, wi=st.w, hk=node.hk,
+                              wk=node.wk, pad=node.pad,
+                              groups=node.groups)
+            handles.append((layer, plan_conv_dw(
+                st.h, st.w, node.ci, node.hk, node.wk,
+                padding=(node.pad,) * 2, norm=node.norm,
+                dtype_bytes=dtype_bytes, vmem_budget=vmem_budget,
+                target=target)))
+            continue
         ci_g, co_g = node.ci // node.groups, node.co // node.groups
         layer = ConvLayer(name=node.name, batch=batch, ci=ci_g, co=co_g,
                           hi=st.h, wi=st.w, hk=node.hk, wk=node.wk,
@@ -327,6 +396,7 @@ def graph_plan_handles(graph: ConvGraph, h: int, w: int, *, batch: int,
                          padding=(node.pad,) * 2,
                          pool=st.pool if st.fused_pool else 1,
                          residual=st.residual,
+                         norm=node.norm and node.groups == 1,
                          dtype_bytes=dtype_bytes,
                          vmem_budget=vmem_budget, target=target)
         if training:
@@ -380,12 +450,13 @@ def graph_training_step_report(graph: ConvGraph, h: int, w: int, *,
         for layer, tp in handles:
             t = tp.traffic(batch)
             words += t.total
-            fwd_words += t.fwd.total
+            # a depthwise handle is its forward alone
+            fwd_words += t.fwd.total if hasattr(t, "fwd") else t.total
             bound += tp.bound_words(layer)
             # grouped layers repeat per group but never ride the kernel
             # dgrad (dgrad_kernel is gated on groups == 1), so the sum
             # counts each kernel-dgrad layer exactly once
-            kernel_layers += int(tp.dgrad_kernel)
+            kernel_layers += int(getattr(tp, "dgrad_kernel", False))
         n_stages = len(graph_stages(graph, h, w, in_ch, strict=strict))
         _sp.set(traffic_bytes=words * dtype_bytes,
                 train_vs_bound_x=words / max(bound, 1e-30))

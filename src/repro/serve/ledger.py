@@ -243,7 +243,9 @@ class TrafficLedger:
             from repro.kernels.conv_lb.ops import plan_conv
             words = 0.0
             for layer in tally.layers_b1:
-                plan = plan_conv(layer.hi, layer.wi, layer.ci, layer.co,
+                # a depthwise layer's weights: one input channel each
+                plan = plan_conv(layer.hi, layer.wi,
+                                 layer.ci // layer.groups, layer.co,
                                  layer.hk, layer.wk, batch=1,
                                  stride=(layer.stride,) * 2,
                                  padding=(layer.pad,) * 2,

@@ -11,6 +11,11 @@ backward's dgrad + wgrad kernels at real widths, on the plans a
   * ResNet-20/32 s2b0_a (3x3 stride 2: strided window loads, and the
     lhs-dilated dgrad) and s2b0_proj (the 1x1 stride-2 projection).
 
+and ConvNeXt-T/224's new kernels: the ``conv_dw`` depthwise 7x7 kernel
+at each stage's width (96 and 192 are not LANE multiples) with its
+fused LayerNorm, the 1x1 convs' GELU and layer-scale + residual +
+LayerNorm epilogues, and the 4x4 stride-4 patch stem.
+
 Each compile must contain the Pallas kernels (``tpu_custom_call``) and
 record no ``exec.fallback``: what interpret mode accepts but Mosaic
 refuses (unaligned tiles, strided value slices, interior padding,
@@ -117,3 +122,54 @@ def test_kernels_carry_their_pass_names(one_chip):
                    .split(".")[0] for line in calls)
     assert names == ["conv_dgrad", "conv_fwd", "conv_wgrad"]
     assert "/conv_fwd/pallas_call" in fwd_hlo
+
+
+# (name: batch, plane, channels) of ConvNeXt-T's depthwise layers
+DEPTHWISE = {"convnext_s1_dw": (8, 56, 96), "convnext_s2_dw": (8, 28, 192),
+             "convnext_s3_dw": (8, 14, 384), "convnext_s4_dw": (8, 7, 768)}
+
+
+@pytest.mark.parametrize("layer", sorted(DEPTHWISE))
+def test_depthwise_kernel_compiles(one_chip, layer):
+    b, hw, c = DEPTHWISE[layer]
+
+    def fwd(x, w, bias, norm):
+        return conv2d_lb(x, w, bias, groups=c, padding=3, norm=norm,
+                         target=COMPILED)
+
+    assert _compile(fwd, one_chip, (b, hw, hw, c), (7, 7, 1, c), (c,),
+                    (2, c)) == 1
+
+
+@pytest.mark.parametrize("layer", ["pw1", "pw2", "stem"])
+def test_convnext_epilogue_kernels_compile(one_chip, layer):
+    """GELU (erf from multiply-adds and a divide), layer scale +
+    residual + LayerNorm, and the Ci=3 stride-4 stem with its norm."""
+    if layer == "pw1":
+        def fwd(x, w, bias):
+            return conv2d_lb(x, w, bias, gelu=True, target=COMPILED)
+        shapes = [(8, 56, 56, 96), (1, 1, 96, 384), (384,)]
+    elif layer == "pw2":
+        def fwd(x, w, bias, scale, res, norm):
+            return conv2d_lb(x, w, bias, res, scale=scale, norm=norm,
+                             target=COMPILED)
+        shapes = [(8, 56, 56, 384), (1, 1, 384, 96), (96,), (96,),
+                  (8, 56, 56, 96), (2, 96)]
+    else:
+        def fwd(x, w, bias, norm):
+            return conv2d_lb(x, w, bias, stride=4, norm=norm,
+                             target=COMPILED)
+        shapes = [(8, 224, 224, 3), (4, 4, 3, 96), (96,), (2, 96)]
+    assert _compile(fwd, one_chip, *shapes) == 1
+
+
+def test_the_depthwise_kernel_carries_its_name(one_chip):
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((8, 14, 14, 384), (7, 7, 1, 384))]
+    hlo = jax.jit(lambda x, w: conv2d_lb(
+        x, w, groups=384, padding=3, target=COMPILED)).lower(
+            *args).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert calls[0].split(" = ")[0].split()[-1].startswith("%conv_dw")
