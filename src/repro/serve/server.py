@@ -37,6 +37,7 @@ from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.exec_target import ExecTarget, from_flags, resolve_target
 from repro.models.cnn import vgg_graph
@@ -54,7 +55,7 @@ class ServeResult:
     """One completed request: logits per image + its traffic charge."""
 
     rid: int
-    logits: Any                # (n_images, n_classes) or None
+    logits: Any                # host (n_images, n_classes) or None
     charge: RequestCharge
     latency_s: float
 
@@ -139,7 +140,7 @@ class ImageServer:
         self.results: dict[int, ServeResult] = {}
         self._counters = {"dispatches": 0, "traces": 0,
                           "pipeline_hits": 0, "plan_hits": 0,
-                          "results_evicted": 0}
+                          "host_copies": 0, "results_evicted": 0}
         self._next_rid = 0
 
     @property
@@ -283,7 +284,12 @@ class ImageServer:
         pipeline.  ``target`` clamps *downward* against the server's
         (:meth:`ExecTarget.clamp`, the one negotiation) — a lax-only
         or account-only server never upgrades; an ``ACCOUNT_ONLY``
-        resolution skips execution entirely."""
+        resolution skips execution entirely.
+
+        Returns the whole ``(bucket, classes)`` logits as one host
+        array, padding rows included: no device op runs between the
+        pipeline and the answers, so a group of any composition
+        compiles nothing after its pipeline."""
         tgt = self.target.clamp(target)
         if not tgt.compute:
             return None
@@ -316,12 +322,19 @@ class ImageServer:
             sp.set(us=dt * 1e6,
                    achieved_gbps=(n_bytes / dt / 1e9)
                    if (n_bytes and dt > 0) else None)
-        return out
+        # one device-to-host copy a dispatch, of the array as it is
+        with tr.span("serve.fetch", bucket=int(bucket),
+                     bytes=int(out.nbytes)):
+            host = np.asarray(out)
+        self.metrics.counter("serve_logits_d2h_bytes").inc(host.nbytes)
+        return host
 
     def _complete(self, group: list[ImageRequest], bucket: int, logits,
                   now: float) -> list[ServeResult]:
         """Bookkeeping half of a dispatch: stamp completion, charge
-        the ledger, publish results into the bounded window."""
+        the ledger, publish results into the bounded window.  Each
+        request's logits are a host slice of ``logits`` (what
+        :meth:`_execute` returned), so this holds no device call."""
         with self.tracer.span("serve.complete", bucket=int(bucket)):
             # virtual clocks (tests) may stand still or even be skewed
             # backwards mid-flight; a completion never predates the
@@ -336,6 +349,8 @@ class ImageServer:
                 latencies={r.rid: r.latency for r in group},
                 model=self.graph.name)
             self._counters["dispatches"] += 1
+            if logits is not None:
+                self._counters["host_copies"] += 1
             results = []
             off = 0
             for r, charge in zip(group, charges):

@@ -546,7 +546,7 @@ def test_traced_serving_annotates_where_the_work_happens(annotations):
     loop = _served(tracer)
     assert loop.counters["done"] == 2
     for name in ("loop.admit", "serve.h2d", "serve.assemble",
-                 "serve.execute", "serve.complete"):
+                 "serve.execute", "serve.fetch", "serve.complete"):
         assert name in annotations, name
         assert all(s.finished for s in tracer.find(name=name))
     # the copy sits inside the admission's locked section
@@ -556,6 +556,14 @@ def test_traced_serving_annotates_where_the_work_happens(annotations):
     assert [s.attrs["n_images"] for s in h2d] == [1, 3]
     assert all(s.attrs["bucket"] in (1, 4)
                for s in tracer.find(name="serve.complete"))
+    # one host copy of each computing dispatch's whole (bucket, 4
+    # classes) float32 logits, after the device call's own span
+    fetch, execute = (tracer.find(name="serve.fetch"),
+                      tracer.find(name="serve.execute"))
+    assert len(fetch) == len(execute) == loop.server.stats["dispatches"]
+    assert [s.attrs["bytes"] for s in fetch] == [
+        s.attrs["bucket"] * 4 * 4 for s in fetch]
+    assert all(f.t0 >= e.t1 for f, e in zip(fetch, execute))
     # the per-request instant events are gone: spans replace them
     assert not tracer.find(name="serve.admit")
     assert not [s for s in tracer.find(name="serve.complete")

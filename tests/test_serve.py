@@ -15,6 +15,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core.lower_bound import q_dram_practical, q_dram_serving
@@ -250,6 +251,86 @@ def test_kernel_and_fallback_pipelines_agree():
         srv.submit(imgs)
         out[target] = srv.poll()[0].logits
     assert jnp.allclose(out["interpret"], out["lax"], atol=2e-4)
+
+
+# --------------------------------------------------------------------------
+# answers from one host copy of each dispatch's logits
+# --------------------------------------------------------------------------
+
+def _mixed_group(srv, sizes, key):
+    """Queue requests of ``sizes`` images on ``srv``; returns their
+    images stacked in submission order."""
+    imgs = jax.random.normal(key, (sum(sizes), 8, 8, 3))
+    off = 0
+    for n in sizes:
+        srv.submit(imgs[off:off + n], now=0.0)
+        off += n
+    return imgs
+
+
+def test_dispatch_answers_host_slices_of_one_copy():
+    """A group of sizes [2, 1, 3] padded into bucket 8: each request
+    gets its own rows of the pipeline's output as a host array, the
+    padding rows are dropped, and the dispatch took one host copy."""
+    params = init_vgg(jax.random.PRNGKey(0), n_classes=4,
+                      width_mult=0.05)
+    srv = ImageServer(params, 8, 8, buckets=(8,), wait_budget=0.0,
+                      target="lax")
+    imgs = _mixed_group(srv, [2, 1, 3], jax.random.PRNGKey(3))
+    results = srv.poll(now=1.0)
+    padded = jnp.pad(imgs, ((0, 2), (0, 0), (0, 0), (0, 0)))
+    rows = np.asarray(srv.pipeline(8)(params, padded))
+    assert [r.charge.bucket for r in results] == [8, 8, 8]
+    assert all(type(r.logits) is np.ndarray for r in results)
+    assert [r.logits.shape for r in results] == [(2, 4), (1, 4), (3, 4)]
+    np.testing.assert_array_equal(
+        np.concatenate([r.logits for r in results]), rows[:6])
+    assert srv.stats["host_copies"] == srv.stats["dispatches"] == 1
+    assert (srv.metrics.counter("serve_logits_d2h_bytes").snapshot()
+            == rows.nbytes == 8 * 4 * 4)
+
+
+def test_account_only_dispatch_takes_no_host_copy():
+    params = init_vgg(jax.random.PRNGKey(0), n_classes=4,
+                      width_mult=0.05)
+    srv = ImageServer(params, 8, 8, buckets=(8,), wait_budget=0.0,
+                      compute=False)
+    for n in (2, 1, 3):
+        srv.submit(n_images=n, now=0.0)
+    results = srv.poll(now=1.0)
+    assert len(results) == 3
+    assert all(r.logits is None for r in results)
+    assert srv.stats["dispatches"] == 1
+    assert srv.stats["host_copies"] == 0
+    assert srv.metrics.counter("serve_logits_d2h_bytes").snapshot() == 0
+
+
+def test_completion_of_a_new_group_compiles_nothing():
+    """Once a bucket is warm, answering a group whose composition was
+    never seen before compiles nothing: ``_complete`` slices the host
+    copy and runs no device op (an eager device slice per request
+    would compile one program per new request size)."""
+    params = init_vgg(jax.random.PRNGKey(0), n_classes=4,
+                      width_mult=0.05)
+    srv = ImageServer(params, 8, 8, buckets=(8,), wait_budget=0.0,
+                      target="lax")
+    srv.warm()
+    _mixed_group(srv, [5, 2], jax.random.PRNGKey(4))
+    group, bucket = srv.queue.pop_ready(1.0)
+    logits = srv._execute(group, bucket)
+    compiles = []
+
+    def on_event(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        results = srv._complete(group, bucket, logits, now=1.0)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert [r.logits.shape for r in results] == [(5, 4), (2, 4)]
+    assert compiles == []
 
 
 # --------------------------------------------------------------------------

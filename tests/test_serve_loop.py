@@ -371,6 +371,43 @@ def test_async_driver_overlaps_up_to_max_inflight():
     _assert_reconciled(loop)
 
 
+@pytest.mark.parametrize("runner", ["run_sync", "run_async"])
+def test_execute_runs_off_lock_and_complete_under_it(runner):
+    """The host copy of a dispatch's logits is taken in ``_execute``,
+    with the loop's lock released, so admission overlaps it; the
+    completion bookkeeping in ``_complete`` runs with the lock held by
+    the completing thread, so a request's terminal state and its
+    ledger row change together."""
+    srv = ImageServer(_tiny_params(), 8, 8, target="lax",
+                      buckets=(1, 2, 4), wait_budget=0.0)
+    loop = ServingLoop(srv, deadline_s=None, max_inflight=2)
+    held = {"_execute": [], "_complete": []}
+
+    def watched(name):
+        inner = getattr(srv, name)
+
+        def call(*a, **kw):
+            held[name].append(loop._lock._is_owned())
+            return inner(*a, **kw)
+        return call
+
+    for name in held:
+        setattr(srv, name, watched(name))
+    images = jnp.ones((4, 8, 8, 3))
+    for n in (1, 2, 1, 4):
+        loop.submit(images[:n])
+    if runner == "run_sync":
+        results = loop.run_sync(tick_s=0.0)
+    else:
+        results = asyncio.run(loop.run_async())
+    assert sum(r.logits.shape[0] for r in results) == 8
+    assert held["_execute"] and not any(held["_execute"])
+    assert len(held["_complete"]) == len(held["_execute"])
+    assert all(held["_complete"])
+    assert srv.stats["host_copies"] == srv.stats["dispatches"]
+    _assert_reconciled(loop)
+
+
 # --------------------------------------------------------------------------
 # fault-injection plumbing
 # --------------------------------------------------------------------------
