@@ -6,7 +6,6 @@
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.tpu_adapter import (BlockShape, arithmetic_intensity,
                                     hbm_traffic_model, lb_block_shape)
@@ -126,7 +125,6 @@ def bench_kernel_walltime():
     """Kernel sanity timings at ``WALLTIME_TARGET`` (interpret by
     default, or lax) — host wall clocks, not TPU performance."""
     from repro.core.exec_target import resolve_target
-    from repro.kernels.attention_block.ops import flash_attention
     from repro.kernels.conv_lb.ops import conv2d_lb
     from repro.kernels.matmul_lb.ops import matmul_lb
 
@@ -150,11 +148,6 @@ def bench_kernel_walltime():
                  _time_call(lambda a, b: conv2d_lb(
                      a, b, padding=1, y_block=12, x_block=12,
                      ci_block=8, co_block=16, target=tgt), xt, wt), 0))
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, 128, 4, 16))
-    kk = jax.random.normal(jax.random.PRNGKey(1), (1, 128, 2, 16))
-    rows.append((f"kernels/flash_attn_128_{tag}_us",
-                 _time_call(lambda a, b: flash_attention(
-                     a, b, b, bq=64, bk=64, target=tgt), q, kk), 0))
     return rows
 
 

@@ -167,7 +167,6 @@ def bench_obs_overhead():
 def bench_obs_kernel_gbps():
     """Every kernel-bench geometry, timed through accounted spans."""
     from repro.core.tpu_adapter import hbm_traffic_model, lb_block_shape
-    from repro.kernels.attention_block.ops import flash_attention
     from repro.kernels.conv_lb.ops import conv2d_lb_timed
     from repro.kernels.matmul_lb.ops import matmul_lb
 
@@ -199,15 +198,6 @@ def bench_obs_kernel_gbps():
     n_bytes = hbm_traffic_model(256, 256, 256, lb_block_shape(256, 256, 256))
     rows.append(("obs/matmul_lb_256/achieved_gbps", us,
                  round(n_bytes / (us / 1e6) / 1e9, 4)))
-
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, 128, 4, 16))
-    kk = jax.random.normal(jax.random.PRNGKey(1), (1, 128, 2, 16))
-    us = timed_call(
-        lambda: flash_attention(q, kk, kk, bq=64, bk=64)
-        .block_until_ready(), tracer=tr, name="kernel.flash_attn")
-    io_bytes = (q.size + 2 * kk.size + q.size) * 4   # q,k,v in + out
-    rows.append(("obs/flash_attn_128/io_gbps", us,
-                 round(io_bytes / (us / 1e6) / 1e9, 4)))
     return rows
 
 

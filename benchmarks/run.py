@@ -11,7 +11,6 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="substring filter on benchmark function names")
-    ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows as JSON to PATH "
                          "(e.g. BENCH_2.json)")
@@ -29,14 +28,11 @@ def main() -> None:
     from benchmarks.obs_bench import ALL_OBS
     from benchmarks.paper_tables import ALL_TABLES
     from benchmarks.plan_audit_bench import ALL_AUDIT
-    from benchmarks.roofline_bench import ALL_ROOFLINE
     from benchmarks.serve_bench import ALL_SERVE
     from benchmarks.train_traffic_bench import ALL_TRAIN
 
     benches = (ALL_TABLES + ALL_KERNELS + ALL_SERVE + ALL_TRAIN
                + ALL_AUDIT + ALL_OBS)
-    if not args.skip_roofline:
-        benches = benches + ALL_ROOFLINE
 
     rows = []
     print("name,us_per_call,derived")

@@ -20,15 +20,14 @@ default to interpret mode by design (CPU validation), but anything
 above them must thread the flag explicitly, or a TPU run silently
 executes the slow interpreter.
 
-``L005`` No bare wall-clock / sleep call inside ``serve/`` or
-``runtime/`` modules: serving and runtime loops must take an
-injectable ``clock=``/``sleep=`` (references in *parameter defaults*
-like ``clock=time.monotonic`` are the sanctioned idiom), or the loop
-can never run under the virtual time the chaos suite and the
-deterministic benchmarks depend on.  Flags call sites of
-``time.monotonic()`` / ``time.sleep()`` / ``time.time()`` /
-``time.perf_counter()``; scoped to path fragments ``/serve/`` and
-``/runtime/`` only.
+``L005`` No bare wall-clock / sleep call inside ``serve/`` modules:
+serving loops must take an injectable ``clock=``/``sleep=``
+(references in *parameter defaults* like ``clock=time.monotonic`` are
+the sanctioned idiom), or the loop can never run under the virtual
+time the chaos suite and the deterministic benchmarks depend on.
+Flags call sites of ``time.monotonic()`` / ``time.sleep()`` /
+``time.time()`` / ``time.perf_counter()``; scoped to the path fragment
+``/serve/`` only.
 
 ``L006`` Observability must stay deterministic and injectable: (a) no
 bare wall-clock / sleep call inside ``obs/`` modules — the tracer's
@@ -56,14 +55,6 @@ loudly-named fallback that records itself via ``record_fallback`` —
 a quiet ``lax.conv`` in a gradient path silently un-does the paper
 dataflow while every plan still claims it rode the kernel.
 
-``L004`` No obviously 0-d value returned from a ``shard_map`` body:
-scalar residuals crossing a differentiated ``shard_map`` break jax
-0.4.x's transpose (``_SpecError`` under ``grad``) — bodies must keep
-everything >= 1-D (see ``models/embedding.py``).  The check is a
-conservative heuristic: it flags ``return``s whose expression (or
-tuple element) is a direct ``jnp.sum/mean/max/min/prod`` call without
-``keepdims=True``, or a ``float(...)`` — shapes it can prove 0-d.
-
 Exit status 0 when the tree is clean, 1 otherwise — tier-1 runs this
 as a test, and ``benchmarks/plan_audit_bench.py`` publishes the error
 count as a gated row.
@@ -81,8 +72,7 @@ LINT_RULES = {
     "L001": "jax shard_map/check_vma imported outside parallel/compat",
     "L002": "hypothesis imported outside tests/_hypothesis_compat",
     "L003": "interpret=True literal default outside src/repro/kernels/",
-    "L004": "provably 0-d value returned from a shard_map body",
-    "L005": "bare wall-clock/sleep call in serve/runtime (inject clock=)",
+    "L005": "bare wall-clock/sleep call in serve/ (inject clock=)",
     "L006": "bare clock in obs/, or set_active tracer mutation outside obs/",
     "L007": "interpret=/use_kernel= kwarg passed outside src/repro/kernels/",
     "L008": "jax.lax.conv* in a backward path outside *_lax_fallback",
@@ -93,7 +83,6 @@ _ALLOW = {
     "L001": ("parallel/compat.py",),
     "L002": ("_hypothesis_compat.py",),
     "L003": ("/kernels/", "core/exec_target.py"),
-    "L004": (),
     "L005": (),
     "L006": (),
     # exec_target.py *defines* the backend abstraction — its singleton
@@ -111,10 +100,8 @@ _OBS_FRAGMENTS = ("/obs/",)
 
 #: path fragments a rule is *scoped to* (empty: applies everywhere)
 _ONLY = {
-    "L005": ("/serve/", "/runtime/"),
+    "L005": ("/serve/",),
 }
-
-_SCALAR_REDUCERS = {"sum", "mean", "max", "min", "prod"}
 
 #: wall-clock call chains L005 rejects outside parameter defaults
 _CLOCK_CALLS = {"time.monotonic", "time.sleep", "time.time",
@@ -154,39 +141,10 @@ def _attr_chain(node: ast.AST) -> str:
     return ""
 
 
-def _returns_scalar(expr: ast.AST) -> bool:
-    """True when ``expr`` is provably a 0-d array/scalar."""
-    if isinstance(expr, ast.Tuple):
-        return any(_returns_scalar(e) for e in expr.elts)
-    if isinstance(expr, ast.Constant) and isinstance(expr.value,
-                                                    (int, float)):
-        return True
-    if not isinstance(expr, ast.Call):
-        return False
-    chain = _attr_chain(expr.func)
-    if chain == "float":
-        return True
-    head, _, tail = chain.rpartition(".")
-    if head in ("jnp", "np", "jax.numpy", "numpy") \
-            and tail in _SCALAR_REDUCERS:
-        for kw in expr.keywords:
-            if kw.arg == "keepdims" \
-                    and isinstance(kw.value, ast.Constant) \
-                    and kw.value.value:
-                return False
-        # a reduction over an explicit axis keeps the other dims
-        return not any(kw.arg == "axis" for kw in expr.keywords) \
-            and len(expr.args) < 2
-    return False
-
-
 class _Linter(ast.NodeVisitor):
     def __init__(self, path: str):
         self.path = path
         self.findings: list[Finding] = []
-        # every def in the module, by name — shard_map bodies are
-        # resolved against this (closures included)
-        self.defs: dict[str, ast.FunctionDef] = {}
         # enclosing function names, outermost first — L008 resolves a
         # call site against the whole lexical chain (a closure inside
         # _bwd is still a backward path; a closure inside
@@ -258,7 +216,6 @@ class _Linter(ast.NodeVisitor):
                            "src/repro/kernels/ only")
 
     def visit_FunctionDef(self, node: ast.FunctionDef) -> None:
-        self.defs.setdefault(node.name, node)
         self._check_defaults(node)
         self.fn_stack.append(node.name)
         try:
@@ -267,21 +224,6 @@ class _Linter(ast.NodeVisitor):
             self.fn_stack.pop()
 
     visit_AsyncFunctionDef = visit_FunctionDef
-
-    # -- L004: scalars out of shard_map bodies ------------------------------
-
-    def _body_returns(self, fn: ast.AST):
-        if isinstance(fn, ast.Lambda):
-            yield fn.body.lineno, fn.body
-            return
-        if isinstance(fn, ast.Call):       # partial(body, ...) et al.
-            fn = fn.args[0] if fn.args else None
-        if isinstance(fn, ast.Name):
-            fn = self.defs.get(fn.id)
-        if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            for sub in ast.walk(fn):
-                if isinstance(sub, ast.Return) and sub.value is not None:
-                    yield sub.lineno, sub.value
 
     def visit_Call(self, node: ast.Call) -> None:
         chain = _attr_chain(node.func)
@@ -323,14 +265,6 @@ class _Linter(ast.NodeVisitor):
                                "pass target= (an ExecTarget) instead; "
                                "raw backend kwargs live under "
                                "src/repro/kernels/ only")
-        if (chain == "shard_map" or chain.endswith(".shard_map")) \
-                and node.args:
-            for line, expr in self._body_returns(node.args[0]):
-                if _returns_scalar(expr):
-                    self._emit("L004", line,
-                               "shard_map body returns a provably 0-d "
-                               "value — keep residuals >= 1-D "
-                               "(reshape to (1,))")
         self.generic_visit(node)
 
 
@@ -343,10 +277,6 @@ def lint_file(path: str | Path) -> list[Finding]:
         return [Finding(rule="parse", path=str(path),
                         line=e.lineno or 0, message=str(e.msg))]
     linter = _Linter(str(path))
-    # two passes so a shard_map call can resolve a body defined later
-    for sub in ast.walk(tree):
-        if isinstance(sub, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            linter.defs.setdefault(sub.name, sub)
     linter.visit(tree)
     return linter.findings
 

@@ -12,8 +12,8 @@ shapes for the MXU/VMEM hierarchy:
                     (assumption change recorded in DESIGN.md §7);
   * WndR         -> halo-extended input blocks chosen for the conv kernel.
 
-Also provides the per-chip communication-balance rule used by the
-mesh-level sharding (the beyond-paper extension)."""
+Also provides the per-chip communication-balance rule for splitting an
+output across a mesh of chips (the beyond-paper extension)."""
 
 from __future__ import annotations
 
@@ -21,10 +21,6 @@ import dataclasses
 import itertools
 
 # --- TPU v5e hardware constants (per chip) ----------------------------------
-PEAK_BF16_FLOPS = 197e12          # MXU bf16
-HBM_BYTES_PER_S = 819e9
-ICI_BYTES_PER_S = 50e9            # per link
-VMEM_BYTES = 128 * 1024 * 1024    # v5e VMEM per core (physical)
 # scoped VMEM one kernel asks Mosaic for (``vmem_limit_bytes``) and the
 # execution planner's default budget — one number, so a plan that fits
 # the budget is a kernel Mosaic grants.  Mosaic's own default scope on
@@ -35,7 +31,6 @@ VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 # vector code over the tile, so a 1 MiB tile keeps each kernel's
 # compile near a second while still feeding the MXU 1-2k-row matmuls
 MOSAIC_TILE_BYTES = 1024 * 1024
-HBM_BYTES = 16 * 1024 * 1024 * 1024
 MXU_DIM = 128                     # systolic array edge
 LANE = 128                        # last-dim tile
 # dtype bytes -> second-minor (sublane) tile: Mosaic packs narrower

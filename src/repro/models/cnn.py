@@ -29,11 +29,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.vgg import _CFG
-from repro.models.graph import (ConvGraph, ConvNode, graph_forward,
-                                graph_logits, graph_plan_handles,
-                                graph_stages, graph_training_step_report,
-                                init_graph)
-from repro.models.layers import dense_init, split_keys
+from repro.models.graph import (ConvGraph, ConvNode, dense_init,
+                                graph_forward, graph_logits,
+                                graph_plan_handles, graph_stages,
+                                graph_training_step_report, init_graph,
+                                split_keys)
 
 
 def vgg_layer_dims(width_mult: float = 1.0):

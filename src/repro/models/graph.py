@@ -196,6 +196,17 @@ def graph_stages(graph: ConvGraph, h: int, w: int, in_ch: int = 3, *,
     return stages
 
 
+def dense_init(key: jax.Array, shape: tuple[int, ...],
+               dtype, fan_in: int | None = None) -> jax.Array:
+    fan_in = fan_in if fan_in is not None else shape[0]
+    std = 1.0 / math.sqrt(fan_in)
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def split_keys(key: jax.Array, n: int) -> list[jax.Array]:
+    return list(jax.random.split(key, n))
+
+
 def init_graph(key, graph: ConvGraph, n_classes: int = 10,
                dtype=jnp.float32) -> dict:
     """He-init conv params for every node + a linear head off the graph
@@ -205,8 +216,6 @@ def init_graph(key, graph: ConvGraph, n_classes: int = 10,
     halves activation variance); linear nodes (e.g. 1x1 projection
     shortcuts) stay at plain He.  Layer scales start at ConvNeXt's
     1e-6, norms at the identity (weight 1, bias 0), a head bias at 0."""
-    from repro.models.layers import dense_init, split_keys
-
     keys = split_keys(key, len(graph.nodes) + 1)
     convs = []
     for k, node in zip(keys, graph.nodes):

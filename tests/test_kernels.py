@@ -6,8 +6,6 @@ import numpy as np
 import pytest
 
 from repro.core.tpu_adapter import BlockShape, lb_block_shape
-from repro.kernels.attention_block.ops import flash_attention
-from repro.kernels.attention_block.ref import attention_ref
 from repro.kernels.conv_lb.ops import conv2d_lb
 from repro.kernels.conv_lb.ref import conv2d_ref
 from repro.kernels.matmul_lb.ops import matmul_lb
@@ -222,37 +220,6 @@ def test_conv_lb_true_spatial_tiling():
     assert ny * nx > 1                      # genuinely tiled
     blk = plan.blocks
     assert blk.y * blk.x * blk.co < 48 * 48 * 16   # psum tile << plane
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,sq,skv,h,kv,hd,win,causal", [
-    (2, 64, 64, 4, 2, 16, 0, True),
-    (1, 100, 100, 8, 8, 32, 0, True),
-    (2, 128, 128, 4, 1, 16, 32, True),
-    (1, 48, 80, 4, 4, 16, 0, False),
-    (1, 33, 65, 2, 1, 8, 16, True),
-])
-def test_flash_attention_sweep(b, sq, skv, h, kv, hd, win, causal, dtype):
-    q = jax.random.normal(jax.random.PRNGKey(0), (b, sq, h, hd),
-                          jnp.float32).astype(dtype)
-    k = jax.random.normal(jax.random.PRNGKey(1), (b, skv, kv, hd),
-                          jnp.float32).astype(dtype)
-    v = jax.random.normal(jax.random.PRNGKey(2), (b, skv, kv, hd),
-                          jnp.float32).astype(dtype)
-    out = flash_attention(q, k, v, window=win, causal=causal,
-                          bq=32, bk=32)
-    ref = attention_ref(q, k, v, window=win, causal=causal)
-    _allclose(out, ref, dtype)
-
-
-def test_flash_attention_block_size_invariance():
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, 96, 4, 16))
-    k = jax.random.normal(jax.random.PRNGKey(1), (1, 96, 2, 16))
-    v = jax.random.normal(jax.random.PRNGKey(2), (1, 96, 2, 16))
-    ref = attention_ref(q, k, v)
-    for bq, bk in [(16, 16), (32, 96), (96, 32), (48, 48)]:
-        _allclose(flash_attention(q, k, v, bq=bq, bk=bk), ref,
-                  jnp.float32)
 
 
 def test_hbm_traffic_model_matches_eq14():

@@ -75,32 +75,6 @@ def test_L003_flags_interpret_true_default_outside_kernels(tmp_path):
     assert [f.rule for f in findings] == ["L003", "L003"]
 
 
-def test_L004_flags_scalar_returns_from_shard_map_bodies(tmp_path):
-    findings = _lint_snippet(tmp_path, """
-        def body(x):
-            return jnp.sum(x)
-
-        out = shard_map(body, mesh=None)(1)
-        out2 = shard_map(lambda x: jnp.mean(x), mesh=None)(1)
-        # axis reductions keep the other dims: not flagged
-        out3 = shard_map(lambda x: jnp.sum(x, axis=0), mesh=None)(1)
-        # keepdims reductions stay >= 1-D: not flagged
-        out4 = shard_map(lambda x: jnp.sum(x, keepdims=True),
-                         mesh=None)(1)
-        """)
-    assert [f.rule for f in findings] == ["L004", "L004"]
-
-
-def test_L004_resolves_partial_wrapped_bodies(tmp_path):
-    findings = _lint_snippet(tmp_path, """
-        def body(x, flag):
-            return jnp.mean(x)
-
-        out = shard_map(partial(body, flag=True), mesh=None)(1)
-        """)
-    assert [f.rule for f in findings] == ["L004"]
-
-
 _CLOCKY = """
     import time
 
@@ -111,14 +85,12 @@ _CLOCKY = """
     """
 
 
-def test_L005_flags_bare_clock_calls_in_serve_and_runtime(tmp_path):
-    import textwrap as tw
-    for scope in ("serve", "runtime"):
-        d = tmp_path / scope
-        d.mkdir()
-        (d / "loopy.py").write_text(tw.dedent(_CLOCKY))
-        rules = [f.rule for f in lint.lint_file(d / "loopy.py")]
-        assert rules == ["L005", "L005", "L005"], scope
+def test_L005_flags_bare_clock_calls_in_serve(tmp_path):
+    d = tmp_path / "serve"
+    d.mkdir()
+    (d / "loopy.py").write_text(textwrap.dedent(_CLOCKY))
+    rules = [f.rule for f in lint.lint_file(d / "loopy.py")]
+    assert rules == ["L005", "L005", "L005"]
 
 
 def test_L005_allows_clock_parameter_defaults(tmp_path):
@@ -134,8 +106,8 @@ def test_L005_allows_clock_parameter_defaults(tmp_path):
     assert not lint.lint_file(d / "injected.py")
 
 
-def test_L005_is_scoped_to_serve_and_runtime_paths(tmp_path):
-    # the same violating code outside serve/ / runtime/ is fine —
+def test_L005_is_scoped_to_serve_paths(tmp_path):
+    # the same violating code outside serve/ is fine —
     # benchmarks and tests time things with wall clocks on purpose
     assert not _lint_snippet(tmp_path, _CLOCKY)
 
@@ -145,7 +117,7 @@ def test_L006_flags_bare_clock_calls_inside_obs(tmp_path):
     d.mkdir()
     (d / "tracey.py").write_text(textwrap.dedent(_CLOCKY))
     rules = [f.rule for f in lint.lint_file(d / "tracey.py")]
-    # obs/ is outside L005's serve/runtime scope, so each bare clock
+    # obs/ is outside L005's serve/ scope, so each bare clock
     # call is exactly one L006 finding
     assert rules == ["L006", "L006", "L006"]
 

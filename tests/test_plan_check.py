@@ -1,11 +1,11 @@
 """Static conv-plan verifier: legality pass, symbolic cross-audit, and
 the planner gates that ride it (``repro.analysis.plan_check``).
 
-The acceptance contract: every ``vgg_graph``/``resnet_graph`` node
-(forward, dgrad, wgrad) audits clean at the paper's 1 MiB accounting
-budget — zero legality errors, exact symbolic-vs-accountant traffic and
-bound agreement — and the planners provably never return an illegal
-plan (``plan_conv`` raises instead).
+The acceptance contract: every node of the benchmark's VGG16,
+ResNet-20 and ConvNeXt-T graphs (forward, dgrad, wgrad) audits clean at
+the paper's 1 MiB accounting budget — zero legality errors, exact
+symbolic-vs-accountant traffic and bound agreement — and the planners
+provably never return an illegal plan (``plan_conv`` raises instead).
 """
 
 import dataclasses
@@ -17,12 +17,15 @@ from _hypothesis_compat import given, settings, st
 
 from repro.analysis import plan_check as pc
 from repro.core.layer import ConvLayer
+from repro.core.lower_bound import q_dram_theorem2
 from repro.core.tpu_adapter import (BlockShape, ConvBlockShape,
                                     conv_lb_block_shape, sublane_for)
 from repro.kernels.conv_lb.ops import (autotune_conv_blocks, plan_conv,
                                        plan_conv_training,
                                        plan_conv_wgrad)
-from repro.models.cnn import init_vgg, resnet_graph, vgg_graph
+from repro.models.cnn import (convnext_graph, init_vgg, resnet_graph,
+                              vgg_graph, vgg_layer_dims)
+from repro.models.graph import graph_plan_handles
 
 MB = 1024 * 1024
 
@@ -36,38 +39,80 @@ def _layer(h, w, ci, co, hk, stride=1, pad=0, batch=4):
 # the acceptance audit: every committed graph, every pass
 # --------------------------------------------------------------------------
 
-def test_vgg_graph_audits_clean_at_paper_budget():
-    graph = vgg_graph(init_vgg(jax.random.PRNGKey(0)))
-    audit = pc.audit_graph(graph, 224, 224, batch=8, vmem_budget=MB,
-                           training=True)
-    assert audit.n_plans == 3 * 13            # fwd+dgrad+wgrad per conv
-    assert audit.n_legal == audit.n_plans, audit.report()
-    assert audit.traffic_mismatches == 0, audit.report()
-    assert audit.bound_mismatches == 0, audit.report()
-    assert audit.ok and audit.legal_frac == 1.0
-    assert audit.report().startswith("plan audit [interpret]: 39/39")
+#: the benchmark configurations: (graph builder, image side, whether
+#: the mosaic audit plans training) — ConvNeXt-T's compiled path is
+#: serving only (its strided s3_down has no Mosaic wgrad plan)
+_CONFIGS = {
+    "vgg16_224": (lambda: vgg_graph(jax.eval_shape(init_vgg,
+                                                   jax.random.PRNGKey(0))),
+                  224, True),
+    "resnet20_32": (resnet_graph, 32, True),
+    "convnext_t_224": (convnext_graph, 224, False),
+}
+
+#: plans per audit at batch 8: (1 MiB training, mosaic) — fwd + dgrad +
+#: wgrad per dense node, one plan per depthwise node
+_N_PLANS = {"vgg16_224": (3 * 13, 3 * 13),
+            "resnet20_32": (3 * 21, 3 * 21),
+            "convnext_t_224": (3 * 40 + 18, 58)}
+
+_NODES = ([("vgg16_224", name) for name, *_ in vgg_layer_dims()]
+          + [("resnet20_32", n.name) for n in resnet_graph().nodes]
+          + [("convnext_t_224", n.name) for n in convnext_graph().nodes])
 
 
-def test_resnet_graph_audits_clean_at_paper_budget():
-    audit = pc.audit_graph(resnet_graph(), 32, 32, batch=8,
-                           vmem_budget=MB, training=True)
-    assert audit.n_plans == 3 * 21
-    assert audit.ok, audit.report()
+@pytest.fixture(scope="module")
+def node_audits():
+    """``{config: {node: [(entry, layer, handle, depthwise)]}}`` from two
+    audits per benchmark configuration at batch 8: training plans at
+    the paper's 1 MiB budget, and the plans a ``target="compiled"`` run
+    executes, planned under the mosaic profile at the scoped VMEM
+    limit."""
+    out = {}
+    for config, (build, hw, mosaic_training) in _CONFIGS.items():
+        graph = build()
+        depthwise = {n.name: n.depthwise for n in graph.nodes}
+        per_node = {n.name: [] for n in graph.nodes}
+        n_plans = []
+        for kw in (dict(vmem_budget=MB, training=True),
+                   dict(target=pc.TARGET_MOSAIC,
+                        training=mosaic_training)):
+            handles = graph_plan_handles(graph, hw, hw, batch=8, **kw)
+            audit = pc.audit_handles(
+                handles, batch=8, vmem_budget=kw.get("vmem_budget"),
+                target=kw.get("target", pc.TARGET_INTERPRET))
+            n_plans.append(audit.n_plans)
+            by_layer = {layer.name: (layer, h) for layer, h in handles}
+            for e in audit.entries:
+                node = e.name.rpartition("/")[0]
+                per_node[node].append((e, *by_layer[node],
+                                       depthwise[node]))
+        out[config] = per_node
+        assert tuple(n_plans) == _N_PLANS[config], (config, n_plans)
+    return out
 
 
-@pytest.mark.parametrize("model", ["vgg16_224", "resnet20_32"])
-def test_compiled_path_plans_audit_clean_under_mosaic(model):
-    """The plans a ``target="compiled"`` run executes — fwd, dgrad and
-    wgrad of every node at the scoped VMEM limit, planned under the
-    mosaic profile — are all mosaic-legal (the ResNet 1x1 stride-2
-    projections included) and account exactly."""
-    if model == "vgg16_224":
-        graph, hw = vgg_graph(init_vgg(jax.random.PRNGKey(0))), 224
-    else:
-        graph, hw = resnet_graph(), 32
-    audit = pc.audit_graph(graph, hw, hw, batch=8, training=True,
-                           target=pc.TARGET_MOSAIC)
-    assert audit.ok and audit.legal_frac == 1.0, audit.report()
+@pytest.mark.parametrize("config,node", _NODES)
+def test_every_node_audits_clean(node_audits, config, node):
+    """Every pass of every node of the benchmark configurations is
+    legal, accounts exactly (symbolic re-derivation == accountant),
+    carries the symbolic bound, and moves no fewer words than a lower
+    bound.  A depthwise plan's bound is the once-per-word floor, so its
+    words may not beat it.  A dense forward's ``bound`` is Eq. (15)'s
+    attainable form, which a plan beats where the whole reduction fits
+    on chip, so its floor is Theorem 2 at the plan's footprint."""
+    entries = node_audits[config][node]
+    assert entries
+    for e, layer, handle, depthwise in entries:
+        assert e.legal, [str(d) for d in e.diagnostics]
+        assert e.traffic_ok, e.name
+        assert e.bound_ok, e.name
+        if depthwise:
+            assert e.words >= e.bound > 0, (e.name, e.words, e.bound)
+        elif e.name.endswith("/fwd"):
+            fwd = getattr(handle, "fwd", handle)
+            floor = q_dram_theorem2(layer, fwd.footprint_elems())
+            assert e.words >= floor, (e.name, e.words, floor)
 
 
 def test_audit_forward_only_handles():

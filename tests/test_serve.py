@@ -549,16 +549,6 @@ def test_example_serve_images_resnet_smoke(monkeypatch, capsys):
     assert "ledger:" in out and "[resnet20]" in out
 
 
-def test_example_serve_batched_smoke(monkeypatch, capsys):
-    mod = _load(REPO / "examples" / "serve_batched.py")
-    monkeypatch.setattr(sys, "argv",
-                        ["serve_batched.py", "--arch", "minitron-4b",
-                         "--requests", "2", "--slots", "2",
-                         "--gen", "2"])
-    mod.main()
-    assert "served 2 requests" in capsys.readouterr().out
-
-
 def test_launch_serve_images_cli_smoke(monkeypatch, capsys):
     """The launch/ driver end to end in account-only mode (paper-scale
     geometry, no compute)."""
